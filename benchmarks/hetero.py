@@ -99,13 +99,15 @@ def bench_hetero() -> Tuple[List[dict], float]:
         return float(np.percentile(vals, q)) * 1e3 if vals else None
 
     def run_mode(dual: bool) -> dict:
-        # dual=True auto-falls back to co-located execution when only one
-        # device is visible — the ratio then honestly measures ~1.0
+        # with one visible device dual mode is explicitly co-located — the
+        # ratio then honestly measures ~1.0
+        devs = jax.devices()
         eng = RealAgentXPUEngine(
             cfg, params, max_len=max_len,
             pool_slots=n_pro + max(2, n_inj) + 1,
             max_fused_steps=16, decode_segment_steps=4,
-            elastic_decode=False, dual_device=dual)
+            elastic_decode=False, dual_device=dual,
+            prefill_device=devs[0] if dual and len(devs) < 2 else None)
         be = eng.backend
         # warm-up: compile every shape of the measured traces (staged
         # prefill buckets + truncation + handoff in dual mode; the mixed

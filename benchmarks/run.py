@@ -18,6 +18,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from benchmarks import figures, hetero, loadgen  # noqa: E402
 from benchmarks.roofline import table as roofline_table  # noqa: E402
+from repro.launch.compile_cache import configure_compile_cache  # noqa: E402
 
 BENCHES = [
     ("fig_op_affinity", figures.bench_op_affinity),
@@ -46,6 +47,7 @@ def main(argv=None) -> None:
                          "decode_throughput for the BENCH_decode.json entry)")
     ap.add_argument("--out", default="experiments/bench")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     os.makedirs(args.out, exist_ok=True)
     if args.only is not None and args.only not in dict(BENCHES):

@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs import get_tiny_config
 from repro.core.engine import RealAgentXPUEngine, stream_printer
 from repro.core.requests import Priority, Request
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import init_params
 
 
@@ -50,8 +51,8 @@ def main():
     ap.add_argument("--kernel-backend", default="xla",
                     choices=["xla", "pallas"],
                     help="attention kernel routing: pallas runs the "
-                         "pool-native kernels (interpret mode off-TPU), "
-                         "xla the lowered reference — identical tokens")
+                         "pool-native kernels (compiled on TPU, "
+                         "interpreted on CPU), xla the lowered reference")
     ap.add_argument("--system-prompt-len", type=int, default=24,
                     help="shared system-prompt tokens prepended to every "
                          "flow's prompt (0 disables); with the prefix "
@@ -77,6 +78,7 @@ def main():
                          "callback DURING the run (streaming arrival path) "
                          "instead of scheduling it in the trace")
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_tiny_config(args.arch)
     if cfg.frontend != "none" or cfg.is_encoder_decoder:

@@ -1681,14 +1681,19 @@ class DualDeviceBackend(JaxRealBackend):
     ``paste_prefix`` use).  ONE host sync per prefill (the first token)
     waits only on the prefill device's dependency chain.
 
+    Without ``prefill_device`` the backend takes device 1 and raises
+    ``MeshDeviceError`` when there is none; passing the decode device
+    itself asks for co-location (``dual_device`` False: every flow runs
+    the inherited in-pool path).
+
     Elastic operator binding (HEG): a prefill falls back to co-located
     execution on the decode device — the inherited in-pool path, byte-
-    identical tokens — when the second device is absent (``dual_device``
-    False: every flow co-locates), the staging queue is at
+    identical tokens — when the staging queue is at
     ``prefill_inflight_max`` (backpressure), the prompt has a prefix-cache
     hit (the matched KV lives in the decode pool; copying it to the
     staging device and back would cost more than the tail forward), or the
-    HEG affinity tables price the prefill-lane ETC above the decode lane.
+    two devices differ in kind and the HEG affinity tables price the
+    prefill-lane ETC above the decode lane.
     The decision is sticky per request so a prefill never migrates devices
     mid-prompt.
 
@@ -1708,12 +1713,10 @@ class DualDeviceBackend(JaxRealBackend):
         self.decode_device = next(iter(self._pool["pos"].devices()))
         pf = prefill_device
         if pf is None:
-            from repro.launch.mesh import (MeshDeviceError,
-                                           dual_stage_devices)
-            try:
-                _, pf = dual_stage_devices()
-            except MeshDeviceError:
-                pf = self.decode_device  # co-located fallback
+            # a second device is required: co-location is asked for by
+            # passing prefill_device=<the decode device>, never inferred
+            from repro.launch.mesh import dual_stage_devices
+            _, pf = dual_stage_devices()
         self.prefill_device = pf
         # staging leans on donation and the in-pool decode tail; the legacy
         # baselines fall back to co-located execution wholesale
@@ -1722,6 +1725,11 @@ class DualDeviceBackend(JaxRealBackend):
                             and self.device_resident)
         self._params_pf = jax.device_put(params, pf) \
             if self.dual_device else None
+        # the HEG prices a heterogeneous SoC's NPU lane against its iGPU;
+        # two devices of one kind run the same programs alike, so its
+        # affinity tables cannot rank them
+        self._price_lanes = (self.dual_device and pf.device_kind
+                             != self.decode_device.device_kind)
         self._staged: set = set()  # rids prefilling on the prefill device
         self._stage_decision: Dict[int, bool] = {}  # sticky per request
         self._tok_dev_pf: Dict[int, object] = {}  # prompt uploads, pf device
@@ -1818,7 +1826,7 @@ class DualDeviceBackend(JaxRealBackend):
         if stage and len(self._staged) >= self.prefill_inflight_max:
             stage = False
             self.colocated_backpressure += 1
-        if stage and self.heg is not None:
+        if stage and self.heg is not None and self._price_lanes:
             # affinity/ETC fallback: co-locate only when the HEG prices the
             # prefill lane MEANINGFULLY worse (>5%) for this tail — the
             # tables put the two lanes within float noise of each other for

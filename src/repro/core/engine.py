@@ -115,9 +115,10 @@ class RealAgentXPUEngine(AgentXPUEngine):
     ``dual_device`` (DESIGN.md §14) selects stage-decoupled execution —
     prefill on a second JAX device, decode + KV pool on device 0, async
     KV handoff at ``prefill_done``.  ``None`` (default) auto-enables iff
-    two devices are visible; ``True`` forces the dual backend (co-located
-    fallback when only one device exists); ``False`` pins the
-    single-device backend."""
+    two devices are visible; ``True`` forces the dual backend, which raises
+    ``MeshDeviceError`` when only one device exists unless
+    ``prefill_device`` names the decode device (explicit co-location);
+    ``False`` pins the single-device backend."""
 
     def __init__(self, cfg: ModelConfig, params,
                  hw: HardwareProfile = INTEL_CORE_ULTRA_5_125H,

@@ -14,8 +14,15 @@ first ``kv_limit`` ring slots of the full cache — the grid subsumes the
 ``truncate_rings`` view copy the XLA path needs.
 
 Quantized pools (DESIGN.md §11) plug in via ``k_scale``/``v_scale``
-(B, S, Hkv) f32: int8 cache tiles are dequantized in VMEM right before the
-score/context matmuls, so the HBM stream stays 1 byte/element.
+(B, S, Hkv) f32: int8 cache tiles are read as is, and each key's scale is
+applied to its score column and to its softmax weight before the context
+matmul — algebraically the dequantized attention, and the HBM stream stays
+1 byte/element.
+
+Every per-slot operand (``slot_pos``, scales) is laid out with a singleton
+second-to-last axis, ``(B, 1, S)`` / ``(B, Hkv, 1, S)``, so its blocks meet
+the TPU (8, 128) tiling rule at every batch size; the kv block is a
+multiple of 128 lanes or the whole ring (``blocks.kv_extent``).
 """
 from __future__ import annotations
 
@@ -26,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import clamp_block, tpu_compiler_params
+from repro.kernels.blocks import kv_extent
 
 NEG_INF = -1e30
 
@@ -48,25 +55,25 @@ def _decode_kernel(cur_pos_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, hd)
     k = k_ref[0, 0].astype(jnp.float32)  # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    if quant:
-        k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-        v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (G, bk)
+    if quant:
+        s = s * ks_ref[0, 0]  # (1, bk) per-key scale
 
     cur = cur_pos_ref[pl.program_id(0)]  # this batch element's position
-    slot = pos_ref[0]  # (bk,) absolute positions of the cache slots
+    slot = pos_ref[0]  # (1, bk) absolute positions of the cache slots
     ok = (slot >= 0) & (slot <= cur)
     if window is not None:
         ok &= slot > cur - window
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1))
     p = jnp.exp(s - m_new[:, None])
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
+    pv = p * vs_ref[0, 0] if quant else p
     acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        pv, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)
@@ -83,15 +90,15 @@ def decode_attention(q, k_cache, v_cache, slot_pos, cur_pos, *, window=None,
     cur_pos: (B,) int32.  Returns (B, Hq, hd).
 
     ``kv_limit`` (static) restricts the kv grid to the first ``kv_limit``
-    ring slots — the caller guarantees every live position sits below it, as
-    in ``kvcache.truncate_rings``.  ``k_scale``/``v_scale`` (B, S, Hkv) f32
-    mark an int8 cache and are applied in-kernel per tile.
+    ring slots, rounded up to whole 128-lane blocks — the caller guarantees
+    every live position sits below it, as in ``kvcache.truncate_rings``.
+    ``k_scale``/``v_scale`` (B, S, Hkv) f32 mark an int8 cache and are
+    applied in-kernel per tile.
     """
     B, Hq, hd = q.shape
     S, Hkv = k_cache.shape[1], k_cache.shape[2]
     G = Hq // Hkv
-    S_eff = S if kv_limit is None else max(1, min(int(kv_limit), S))
-    block_k = clamp_block(S_eff, block_k)
+    S_eff, block_k = kv_extent(S, kv_limit, block_k)
     n_kv = S_eff // block_k
     scale = 1.0 / (hd ** 0.5)
     quant = k_scale is not None
@@ -109,14 +116,15 @@ def decode_attention(q, k_cache, v_cache, slot_pos, cur_pos, *, window=None,
         pl.BlockSpec((1, 1, G, hd), lambda b, h, ki: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, block_k, hd), lambda b, h, ki: (b, h, ki, 0)),
         pl.BlockSpec((1, 1, block_k, hd), lambda b, h, ki: (b, h, ki, 0)),
-        pl.BlockSpec((1, block_k), lambda b, h, ki: (b, ki)),
+        pl.BlockSpec((1, 1, block_k), lambda b, h, ki: (b, 0, ki)),
     ]
-    inputs = [cur_pos.astype(jnp.int32), qg, kc, vc, slot_pos]
+    inputs = [cur_pos.astype(jnp.int32), qg, kc, vc,
+              slot_pos.astype(jnp.int32)[:, None, :]]  # (B, 1, S)
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, block_k), lambda b, h, ki: (b, h, ki)),
-                     pl.BlockSpec((1, 1, block_k), lambda b, h, ki: (b, h, ki))]
-        inputs += [jnp.swapaxes(k_scale, 1, 2),  # (B, Hkv, S)
-                   jnp.swapaxes(v_scale, 1, 2)]
+        in_specs += [pl.BlockSpec((1, 1, 1, block_k),
+                                  lambda b, h, ki: (b, h, 0, ki))] * 2
+        inputs += [jnp.swapaxes(sc, 1, 2)[:, :, None, :].astype(jnp.float32)
+                   for sc in (k_scale, v_scale)]  # (B, Hkv, 1, S)
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -128,7 +136,7 @@ def decode_attention(q, k_cache, v_cache, slot_pos, cur_pos, *, window=None,
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="decode_attention",

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import clamp_block, tpu_compiler_params
+from repro.kernels.blocks import clamp_block, kv_extent, lane_block
 
 NEG_INF = -1e30
 
@@ -119,7 +119,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, pos_base=0,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -145,12 +145,11 @@ def _flash_pool_kernel(pos_q_ref, pos_kv_ref, q_ref, k_ref, v_ref, *rest,
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (bq, hd)
     k = k_ref[0, 0].astype(jnp.float32)  # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    if quant:
-        k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-        v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq, bk)
-    pq = pos_q_ref[0][:, None]   # (bq, 1) absolute positions of the chunk
-    pk = pos_kv_ref[0][None, :]  # (1, bk) ring-slot positions (-1 = empty)
+    if quant:
+        s = s * ks_ref[0, 0]  # (1, bk) per-key scale
+    pq = pos_q_ref[0]   # (bq, 1) absolute positions of the chunk
+    pk = pos_kv_ref[0]  # (1, bk) ring-slot positions (-1 = empty)
     ok = (pk >= 0) & (pk <= pq)
     if window is not None:
         ok &= pk > pq - window
@@ -160,8 +159,9 @@ def _flash_pool_kernel(pos_q_ref, pos_kv_ref, q_ref, k_ref, v_ref, *rest,
     p = jnp.exp(s - m_new[:, None])
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
+    pv = p * vs_ref[0, 0] if quant else p
     acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        pv, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)
@@ -188,15 +188,17 @@ def flash_attention_pool(q, k, v, pos_q, pos_kv, *, window=None,
     scored and masking does the rest.
 
     ``kv_limit`` (static) restricts the kv grid to the first ``kv_limit``
-    ring slots; ``k_scale``/``v_scale`` (B, Hkv, Skv) f32 mark an int8 ring
-    and dequantize in-kernel.  Returns (B, Hq, Sq, hd).
+    ring slots, rounded up to whole 128-lane blocks; ``k_scale``/``v_scale``
+    (B, Hkv, Skv) f32 mark an int8 ring and dequantize in-kernel.  The
+    position and scale operands get singleton axes so every block meets the
+    TPU (8, 128) tiling rule (see ``decode_attention``).  Returns
+    (B, Hq, Sq, hd).
     """
     B, Hq, Sq, hd = q.shape
     Hkv, Skv = k.shape[1], k.shape[2]
     G = Hq // Hkv
-    Skv_eff = Skv if kv_limit is None else max(1, min(int(kv_limit), Skv))
-    block_q = clamp_block(Sq, block_q)
-    block_k = clamp_block(Skv_eff, block_k)
+    Skv_eff, block_k = kv_extent(Skv, kv_limit, block_k)
+    block_q = lane_block(Sq, block_q)
     n_q = Sq // block_q
     n_kv = Skv_eff // block_k
     scale = 1.0 / (hd ** 0.5)
@@ -206,8 +208,8 @@ def flash_attention_pool(q, k, v, pos_q, pos_kv, *, window=None,
                                scale=scale, quant=quant)
     grid = (B, Hq, n_q, n_kv)
     in_specs = [
-        pl.BlockSpec((1, block_q), lambda b, h, qi, ki: (b, qi)),
-        pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (b, ki)),
+        pl.BlockSpec((1, block_q, 1), lambda b, h, qi, ki: (b, qi, 0)),
+        pl.BlockSpec((1, 1, block_k), lambda b, h, qi, ki: (b, 0, ki)),
         pl.BlockSpec((1, 1, block_q, hd),
                      lambda b, h, qi, ki: (b, h, qi, 0)),
         pl.BlockSpec((1, 1, block_k, hd),
@@ -215,13 +217,14 @@ def flash_attention_pool(q, k, v, pos_q, pos_kv, *, window=None,
         pl.BlockSpec((1, 1, block_k, hd),
                      lambda b, h, qi, ki: (b, h // G, ki, 0)),
     ]
-    inputs = [pos_q.astype(jnp.int32), pos_kv.astype(jnp.int32), q, k, v]
+    inputs = [pos_q.astype(jnp.int32)[:, :, None],   # (B, Sq, 1) column
+              pos_kv.astype(jnp.int32)[:, None, :],  # (B, 1, Skv) row
+              q, k, v]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, block_k), lambda b, h, qi, ki: (b, h // G, ki)),
-            pl.BlockSpec((1, 1, block_k), lambda b, h, qi, ki: (b, h // G, ki)),
-        ]
-        inputs += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, 1, 1, block_k),
+                                  lambda b, h, qi, ki: (b, h // G, 0, ki))] * 2
+        inputs += [sc.astype(jnp.float32)[:, :, None, :]
+                   for sc in (k_scale, v_scale)]  # (B, Hkv, 1, Skv)
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -234,7 +237,7 @@ def flash_attention_pool(q, k, v, pos_q, pos_kv, *, window=None,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _moe_gemm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_d):
     di = pl.program_id(3)
@@ -62,7 +60,7 @@ def moe_gemm(x, w, *, block_c=128, block_f=128, block_d=512,
                                lambda e, ci, fi, di: (e, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,10 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute under ``interpret=True`` — the
-kernel body runs in Python per grid step, which validates the exact TPU
-dataflow.  On a real TPU backend ``interpret`` defaults to False and the
-Mosaic-compiled kernels run.  Select with ``use_pallas='auto'|True|False`` in
-the model ctx (transformer.py) or call these directly.
+``interpret`` defaults from the platform: compiled Mosaic kernels on
+``tpu``; interpret mode (the kernel body run per grid step on the host,
+which checks the TPU dataflow) on ``cpu``, where the tests run; any other
+platform is an error rather than a silent interpreter.  The model reaches
+these through ``kernel_backend="pallas"`` (transformer.py).
 """
 from __future__ import annotations
 
@@ -19,7 +19,12 @@ from repro.kernels import (decode_attention as _dec, flash_attention as _fa,
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for TPU (and are interpreted on "
+            f"CPU); platform {platform!r} has neither path")
+    return platform == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

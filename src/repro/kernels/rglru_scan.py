@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _rglru_kernel(x_ref, a_log_ref, gate_ref, h0_ref, o_ref, hout_ref,
                   h_ref, *, chunk, n_chunks):
@@ -85,7 +83,7 @@ def rglru_scan(x, a_log, gate, h0, *, chunk=128, block_w=512,
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="rglru_scan",

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref,
                  state_ref, *, chunk, head_dim, n_chunks):
@@ -102,7 +100,7 @@ def rwkv6_scan(r, k, v, w_log, u, *, chunk=32, interpret=False):
             jax.ShapeDtypeStruct((BH, D, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="rwkv6_scan",

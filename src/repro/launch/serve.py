@@ -4,7 +4,9 @@
         --scheduler agent.xpu --rate 1.0 --horizon 300
 
 Default mode is the timing simulator (paper-figure methodology); --real runs
-actual token generation with a tiny model under the same scheduler.
+actual token generation under the same scheduler: a tiny float32 preset of
+the arch by default (the CPU test size), or the registry config at its
+published widths in bf16 with --full-width (a chip's worth of memory).
 """
 from __future__ import annotations
 
@@ -19,7 +21,7 @@ from repro.core.annotation import PROFILES
 from repro.core.engine import RealAgentXPUEngine
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b")
     ap.add_argument("--scheduler", default="agent.xpu",
@@ -35,7 +37,14 @@ def main(argv=None):
     ap.add_argument("--horizon", type=float, default=300.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--real", action="store_true",
-                    help="actually generate tokens (tiny model)")
+                    help="actually generate tokens (tiny model unless "
+                         "--full-width)")
+    ap.add_argument("--full-width", action="store_true",
+                    help="with --real: serve the registry config at its "
+                         "published widths with bf16 params and KV "
+                         "(random weights from --seed); default is the "
+                         "tiny float32 preset.  KV rings hold 2048 tokens "
+                         "per slot (256 for the preset)")
     ap.add_argument("--stream", action="store_true",
                     help="with --real: print tokens as they are generated")
     ap.add_argument("--max-fused-steps", type=int, default=32,
@@ -81,8 +90,8 @@ def main(argv=None):
                     choices=["xla", "pallas"],
                     help="with --real: attention kernel routing. pallas "
                          "runs the pool-native decode/prefill kernels "
-                         "(interpret mode off-TPU); xla is the lowered "
-                         "reference — both serve identical tokens")
+                         "(compiled on TPU, interpreted on CPU); xla is "
+                         "the lowered reference")
     ap.add_argument("--pool-slots-max", type=int, default=None,
                     help="hard cap on KV occupancy (live flows + prefix "
                          "snapshot rows).  At saturation arrivals walk the "
@@ -127,7 +136,64 @@ def main(argv=None):
                          "system prompts / tool schemas — the traffic shape "
                          "the prefix cache exists for; 0 disables)")
     ap.add_argument("--json", action="store_true")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def build_model(args):
+    """``(cfg, params, dtype)`` for --real: the tiny float32 preset, or with
+    --full-width the registry config in bf16.  Params are made by one
+    jitted program, so the float32 draws inside ``init_params`` never sit
+    in memory beside the whole bf16 tree."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from repro.models import init_params
+    if args.full_width:
+        cfg, dtype = get_config(args.arch), jnp.bfloat16
+    else:
+        cfg, dtype = get_tiny_config(args.arch), jnp.float32
+    params = jax.jit(functools.partial(init_params, cfg, dtype=dtype))(
+        jax.random.PRNGKey(args.seed))
+    return cfg, params, dtype
+
+
+def ring_len(args) -> int:
+    """KV ring length per pool slot."""
+    return 2048 if args.full_width else 256
+
+
+def real_engine(args, cfg, params, dtype) -> RealAgentXPUEngine:
+    """The real engine as the launcher's flags describe it (shared with
+    chip_smoke.py, which drives the same construction)."""
+    import jax
+    return RealAgentXPUEngine(
+        cfg, params, scheduler=args.scheduler, max_len=ring_len(args),
+        dtype=dtype, pool_slots=args.pool_slots,
+        max_fused_steps=args.max_fused_steps,
+        abortable_runs=not args.no_abortable_runs,
+        decode_segment_steps=args.decode_segment_steps,
+        device_resident=not args.no_device_resident,
+        # None follows device_resident (in-pool prefill leans on
+        # donation; --no-device-resident restores the full legacy flow)
+        in_pool_prefill=False if args.no_in_pool_prefill else None,
+        elastic_decode=not args.no_elastic_decode,
+        prefix_cache=not args.no_prefix_cache,
+        kv_dtype=args.kv_dtype, kernel_backend=args.kernel_backend,
+        pool_slots_max=args.pool_slots_max,
+        admission_queue_len=args.admission_queue_len,
+        deadline_s=None if args.deadline_ms is None
+        else args.deadline_ms / 1000.0,
+        isolate_flow_faults=not args.no_isolate_flow_faults,
+        strict_invariants=True if args.strict_invariants else None,
+        dual_device=False if args.no_dual_device else None,
+        prefill_device=None if args.prefill_device is None
+        else jax.devices()[args.prefill_device],
+        prefill_inflight_max=args.prefill_inflight_max)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     wl = WorkloadConfig(proactive_rate=args.rate,
                         reactive_interval=args.reactive_interval,
@@ -137,11 +203,10 @@ def main(argv=None):
     reqs = generate_workload(wl)
 
     if args.real:
-        import jax
-        import jax.numpy as jnp
-        from repro.models import init_params
-        cfg = get_tiny_config(args.arch)
-        params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+        from repro.launch.compile_cache import configure_compile_cache
+        configure_compile_cache()
+        cfg, params, dtype = build_model(args)
+        max_len = ring_len(args)
         rng = np.random.default_rng(args.seed)
         # agentic traffic shape: every flow shares the same leading system
         # prompt, so all but the first prefill can start at the hit boundary
@@ -149,35 +214,14 @@ def main(argv=None):
         sys_toks = rng.integers(0, cfg.vocab_size, (1, sys_len)) \
             if sys_len else None
         for r in reqs:
-            r.prompt_len = min(r.prompt_len, 96)
-            r.max_new_tokens = min(r.max_new_tokens, 16)
+            # clip the workload's lengths to the ring (96 + 16 at 256)
+            r.prompt_len = min(r.prompt_len, max_len * 3 // 8)
+            r.max_new_tokens = min(r.max_new_tokens, max_len // 16)
             tail = rng.integers(0, cfg.vocab_size, (1, r.prompt_len))
             r.tokens = tail if sys_toks is None else \
                 np.concatenate([sys_toks, tail], axis=1)
             r.prompt_len = r.tokens.shape[1]
-        eng = RealAgentXPUEngine(
-            cfg, params, scheduler=args.scheduler, max_len=256,
-            pool_slots=args.pool_slots,
-            max_fused_steps=args.max_fused_steps,
-            abortable_runs=not args.no_abortable_runs,
-            decode_segment_steps=args.decode_segment_steps,
-            device_resident=not args.no_device_resident,
-            # None follows device_resident (in-pool prefill leans on
-            # donation; --no-device-resident restores the full legacy flow)
-            in_pool_prefill=False if args.no_in_pool_prefill else None,
-            elastic_decode=not args.no_elastic_decode,
-            prefix_cache=not args.no_prefix_cache,
-            kv_dtype=args.kv_dtype, kernel_backend=args.kernel_backend,
-            pool_slots_max=args.pool_slots_max,
-            admission_queue_len=args.admission_queue_len,
-            deadline_s=None if args.deadline_ms is None
-            else args.deadline_ms / 1000.0,
-            isolate_flow_faults=not args.no_isolate_flow_faults,
-            strict_invariants=True if args.strict_invariants else None,
-            dual_device=False if args.no_dual_device else None,
-            prefill_device=None if args.prefill_device is None
-            else jax.devices()[args.prefill_device],
-            prefill_inflight_max=args.prefill_inflight_max)
+        eng = real_engine(args, cfg, params, dtype)
         from repro.core.engine import stream_printer
         on_token = stream_printer() if args.stream else None
         for r in reqs:
@@ -196,7 +240,7 @@ def main(argv=None):
                   f"({st['aborted_steps']} unlaunched steps cancelled)")
             print(f"[real] elastic decode: last dispatch "
                   f"{st['decode_rows']}/{st['pool_slots']} rows x "
-                  f"kv_limit {st['decode_kv_limit']}/256, "
+                  f"kv_limit {st['decode_kv_limit']}/{max_len}, "
                   f"{st['kv_bytes_decode']} KV bytes streamed")
             print(f"[real] kv pool: dtype {st['kv_dtype']}, "
                   f"kernel backend {st['kernel_backend']}, "

@@ -367,9 +367,13 @@ def _attn_mix_extend(cfg, lp, x, st, ctx):
         st = dict(st, k=write(st["k"], k), v=write(st["v"], v),
                   slot_pos=write(st["slot_pos"], pos))
     # Pallas hot path (kernel_backend="pallas"): same mask semantics as the
-    # XLA reference, GQA done natively in-kernel.  Sharded runs keep the XLA
-    # path — the kernels are single-device.
-    pallas = ctx.get("kernel_backend") == "pallas" and not ctx.get("tp_axis")
+    # XLA reference, GQA done natively in-kernel.  The kernels are
+    # single-device, so a sharded run must ask for the XLA path.
+    pallas = ctx.get("kernel_backend") == "pallas"
+    if pallas and ctx.get("tp_axis"):
+        raise NotImplementedError(
+            "kernel_backend='pallas' is single-device; a tp_axis-sharded "
+            "run needs kernel_backend='xla'")
     if C == 1:
         if pallas:
             from repro.kernels import ops as kops

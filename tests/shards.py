@@ -25,6 +25,7 @@ SHARDS: Dict[str, List[str]] = {
         "test_backend.py",
         "test_preemption_real.py",
         "test_kernels.py",
+        "test_tpu_compile.py",
         "test_system.py",
         "test_scheduler.py",
         "test_configs.py",
@@ -33,6 +34,7 @@ SHARDS: Dict[str, List[str]] = {
         "test_quant_kv.py",
         "test_elastic_decode.py",
         "test_consistency.py",
+        "test_reference.py",
         "test_property.py",
         "test_hlocost.py",
         "test_train_data.py",

@@ -6,9 +6,11 @@ backpressure, mesh construction fails typed on short device lists, and
 the contention calibration threads through the scheduler without
 perturbing the sim==real trace invariant.
 
-Runs on one device (every staged path falls back to the inherited
-co-located execution, which must stay byte-identical) and on the pinned
-two-device CI leg (``XLA_FLAGS=--xla_force_host_platform_device_count=2``
+Runs on one device (the engine tests then ask for co-location
+explicitly — ``prefill_device`` = the decode device — and the inherited
+path must stay byte-identical; asking for the dual backend without a
+second device raises) and on the pinned two-device CI leg
+(``XLA_FLAGS=--xla_force_host_platform_device_count=2``
 + ``REPRO_EXPECT_TWO_DEVICES=1``, where a silently single-device jax
 must FAIL, not skip)."""
 import copy
@@ -56,9 +58,18 @@ def _cfg_params():
     return _STATE["cfg"], _STATE["params"]
 
 
+def _colocate_unless_two():
+    """The dual backend needs a second device; with one, co-location is
+    requested explicitly (prefill on the decode device)."""
+    import jax
+    return {} if _n_devices() >= 2 else {"prefill_device": jax.devices()[0]}
+
+
 def _real_engine(dual, **kw):
     from repro.core.engine import RealAgentXPUEngine
     cfg, params = _cfg_params()
+    if dual:
+        kw = {**_colocate_unless_two(), **kw}
     return cfg, params, RealAgentXPUEngine(cfg, params, dual_device=dual,
                                            **kw)
 
@@ -140,6 +151,19 @@ def test_dual_device_mesh_and_stage_order():
     assert dec != pf
 
 
+def test_forced_dual_device_raises_without_second_device():
+    """``dual_device=True`` with one device and no ``prefill_device`` must
+    fail typed instead of quietly co-locating."""
+    from repro.core.engine import RealAgentXPUEngine
+    from repro.launch.mesh import MeshDeviceError
+    if _n_devices() >= 2:
+        pytest.skip("only meaningful with a single device")
+    cfg, params = _cfg_params()
+    with pytest.raises(MeshDeviceError):
+        RealAgentXPUEngine(cfg, params, max_len=64, pool_slots=2,
+                           dual_device=True)
+
+
 # -- token exactness: dual vs single on the mixed trace -----------------------
 def test_dual_engine_token_exact_mixed_trace():
     """Every flow of the mixed preemption/prefix-hit trace streams
@@ -171,7 +195,7 @@ def test_dual_engine_token_exact_mixed_trace():
         assert st["colocated_hits"] >= 1  # the duplicate-prompt flow
         assert st["prefill_device"] != st["decode_device"]
     else:
-        assert not st["dual_device"]  # honest co-located fallback
+        assert not st["dual_device"]  # explicitly co-located
 
 
 def test_sim_and_real_dual_traces_identical_with_aborts():
@@ -199,6 +223,32 @@ def test_sim_and_real_dual_traces_identical_with_aborts():
     assert eng_real.stats()["aborted_runs"] > 0
     assert eng_sim.last_trace == eng_real.last_trace
     assert m_sim.sim_time == m_real.sim_time
+
+
+def test_same_kind_devices_stage_despite_heg_affinity():
+    """The HEG's lane tables price an NPU against an iGPU.  Two devices of
+    one kind must still stage a cold prompt when those tables price the
+    prefill lane out, as they do at full Llama-3.2-3B widths."""
+    _require_two()
+    from repro.core.backend import DualDeviceBackend
+
+    class SlowPrefillLane:
+        def prefill_time_estimate(self, prompt_len, lane="npu"):
+            return 2.0 if lane == "npu" else 1.0
+
+    cfg, params = _cfg_params()
+    be = DualDeviceBackend(cfg, params, pool_slots=2, max_len=256,
+                           heg=SlowPrefillLane())
+    r = Request(id=1, priority=Priority.PROACTIVE, prompt_len=160,
+                max_new_tokens=4, arrival_time=0.0,
+                tokens=np.random.default_rng(5).integers(
+                    0, cfg.vocab_size, (1, 160)))
+    be.register(r)
+    be.prefill_chunk(r, 0, 128, 0.0)
+    assert 1 in be._staged
+    assert be.colocated_affinity == 0
+    be.release([r], 0.0)
+    assert be.validate() == []
 
 
 # -- KV handoff lifecycle (direct backend drive, 2 devices) -------------------

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.compat import clamp_block
+from repro.kernels.blocks import clamp_block, kv_extent
 from repro.models import attention as A
 from repro.models import dequantize_kv, quantize_kv
 
@@ -279,6 +279,21 @@ def test_clamp_block_divisors():
     assert clamp_block(7, 4) == 1  # prime extent
     with pytest.raises(ValueError):
         clamp_block(0, 128)
+
+
+@pytest.mark.parametrize("extent,kv_limit,block,want", [
+    (2048, None, 512, (2048, 512)),
+    (2048, 64, 512, (128, 128)),  # a short prefix still fills 128 lanes
+    (2048, 200, 512, (256, 256)),
+    (2048, 4096, 512, (2048, 512)),  # clipped to the ring
+    (384, None, 256, (384, 128)),  # largest 128-multiple dividing 384
+    (64, None, 512, (64, 64)),  # a truncated view: the whole extent
+    (48, 40, 8, (48, 48)),  # not a 128-multiple: never a partial block
+])
+def test_kv_extent_lane_blocks(extent, kv_limit, block, want):
+    assert kv_extent(extent, kv_limit, block) == want
+    eff, blk = want
+    assert eff % blk == 0 and (blk % 128 == 0 or blk == eff)
 
 
 def test_decode_kernel_default_blocks_small_ring():

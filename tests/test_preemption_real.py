@@ -122,13 +122,16 @@ def test_reactive_abort_mid_run_token_exact():
 
 
 def test_reactive_abort_token_exact_dual_device():
-    """DESIGN.md §14: the dual-device engine — or its co-located fallback
-    when only one device is visible — preserves the §8 mid-run abort
+    """DESIGN.md §14: the dual-device engine — explicitly co-located when
+    only one device is visible — preserves the §8 mid-run abort
     exactness unchanged (staged prefill and KV handoff are backend-local,
     so a reactive arriving mid-fused-run still truncates the plan and
     every flow replays token-exactly)."""
-    cfg, params, eng = _tiny_real_engine(decode_segment_steps=2,
-                                         dual_device=True)
+    import jax
+    devs = jax.devices()
+    cfg, params, eng = _tiny_real_engine(
+        decode_segment_steps=2, dual_device=True,
+        prefill_device=devs[1] if len(devs) >= 2 else devs[0])
     rng = np.random.default_rng(41)
     n, out = 3, 24
     pro = _mk_requests(cfg, rng, [0.0] * n, [12, 14, 16], out)
@@ -291,8 +294,10 @@ def test_failed_run_releases_slots():
 def test_release_mid_prefill_no_double_free_and_clean_rebind():
     """A request released mid-prefill (slot returned at PR 3's
     slot-at-prefill-start lifetime) cannot double-release its slot, and the
-    row rebinds cleanly even when the pool grows before the rebind."""
-    cfg, params, eng = _tiny_real_engine(pool_slots=1)
+    row rebinds cleanly even when the pool grows before the rebind.  The
+    slot lifetime under test is the in-pool one, so the single-device
+    backend is pinned (a staged prefill binds no slot before handoff)."""
+    cfg, params, eng = _tiny_real_engine(pool_slots=1, dual_device=False)
     be = eng.backend
     rng = np.random.default_rng(61)
     a, b, c = _mk_requests(cfg, rng, [0.0] * 3, [24, 20, 16], 3)
