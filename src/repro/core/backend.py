@@ -48,7 +48,8 @@ from repro.core.faults import (AllocationFault, FaultError, FaultInjector,
                                FlowFault, InvariantViolation,
                                PermanentDeviceFault, TransientDeviceFault)
 from repro.core.prefixcache import (PrefixCache, prefix_reuse_supported)
-from repro.core.requests import Request
+from repro.core.requests import Priority, Request
+from repro.core.tracing import NO_PHASES, Phases
 
 TokenCallback = Callable[[Request, int], None]
 
@@ -63,6 +64,10 @@ def _prompt_key(req: Request) -> tuple:
 
 class ExecutionBackend:
     """Interface the scheduler drives through kernel-completion hooks."""
+
+    # spans and phase clocks of the threads that drive the backend
+    # (core/tracing.py); none where nothing runs on a device
+    phases = NO_PHASES
 
     def register(self, req: Request,
                  on_token: Optional[TokenCallback] = None) -> None:
@@ -269,6 +274,25 @@ def _next_pow2(n: int) -> int:
     return b
 
 
+def _launch_meta(key: tuple) -> dict:
+    """Span metadata of a program's launches, from its jit key: the kind,
+    and the rows, KV extent and steps or tokens where the key has them."""
+    kind = key[0]
+    if kind == "decode":
+        return {"kind": kind, "rows": key[2], "kv_limit": key[3], "steps": 1}
+    if kind == "decode_run":
+        return {"kind": kind, "rows": key[3], "kv_limit": key[4],
+                "steps": key[2]}
+    if kind == "prefill_chunk":
+        return {"kind": kind, "tokens": sum(key[2]), "kv_limit": key[4]}
+    return {"kind": kind}
+
+
+# phase clocks that time a decode segment: its launches (with any first
+# compile) and the fetch of its token block
+_SEGMENT_CLOCKS = ("host_launch_s", "host_compile_s", "host_fetch_s")
+
+
 class JaxRealBackend(ExecutionBackend):
     """Real execution on a device-resident slot-pool KV cache.
 
@@ -463,6 +487,12 @@ class JaxRealBackend(ExecutionBackend):
         self._fused_slots: Optional[frozenset] = None
         self._fused_left = 0
         self._jit_cache: Dict[tuple, object] = {}
+        # jitted program -> (jit key, its launch spans' metadata), and the
+        # programs launched at least once (a first launch compiles)
+        self._programs: Dict[object, tuple] = {}
+        self._launched: set = set()
+        # host spans and exclusive phase clocks (core/tracing.py)
+        self.phases = Phases()
         # counters (reported by examples/ and asserted by tests/test_backend)
         self.jit_compilations = 0
         self.decode_device_calls = 0
@@ -476,13 +506,25 @@ class JaxRealBackend(ExecutionBackend):
         self.prefill_host_syncs = 0  # first-token fetches (1 per prefill)
         self.bind_device_calls = 0  # full-row bind scatters (0 in-pool)
         self.kv_bytes_prefill = 0  # prompt-phase KV bytes written
-        # elastic decode accounting (DESIGN.md §9): extent of the most
-        # recent decode dispatch and the cumulative KV bytes decode
+        # elastic decode accounting (DESIGN.md §9): decode_rows and
+        # decode_kv_limit are gauges of the LAST decode dispatch only (not
+        # totals); kv_bytes_decode is the cumulative KV bytes decode
         # programs streamed (rows x kv_limit x steps x per-slot ring bytes
         # — the full-pool baseline pays pool x max_len every step)
         self.decode_rows = 0
         self.decode_kv_limit = 0
         self.kv_bytes_decode = 0
+        # cumulative over launched decode steps: KV positions each step
+        # reads and writes back (rows x kv_limit), and of those the
+        # positions live rows hold (pos + 1 per dispatched row)
+        self.decode_kv_positions = 0
+        self.decode_kv_positions_live = 0
+        # queue wait: wall seconds from a flow's submit to the launch of
+        # its first prefill chunk, and how many flows began prefill, by
+        # priority; _waiting holds the submit instants of flows not begun
+        self._waiting: Dict[int, float] = {}
+        self.queue_wait_s = {Priority.REACTIVE: 0.0, Priority.PROACTIVE: 0.0}
+        self.queue_waits = {Priority.REACTIVE: 0, Priority.PROACTIVE: 0}
         # shared-prefix KV reuse (DESIGN.md §10): a host-side radix index
         # over prompt token IDs; a hit replaces the matched prefix's forward
         # passes with ONE bounded row-to-row KV copy.  Only exact for
@@ -537,8 +579,13 @@ class JaxRealBackend(ExecutionBackend):
         if fn is None:
             if not self.device_resident:
                 donate = ()  # legacy mode: every call copies its pool
-            fn = self._jax.jit(build(), donate_argnums=donate)
+            body = build()
+            # the program is named after its kind: jit_decode_run,
+            # jit_prefill_chunk, ... in the profiler's XLA Modules line
+            body.__name__ = body.__qualname__ = key[0]
+            fn = self._jax.jit(body, donate_argnums=donate)
             self._jit_cache[key] = fn
+            self._programs[fn] = (key, _launch_meta(key))
             self.jit_compilations += 1
         return fn
 
@@ -552,19 +599,32 @@ class JaxRealBackend(ExecutionBackend):
         of DESIGN.md §8 when the program is a decode segment.  Transient
         faults are retried up to ``device_fault_retries_max`` times, then
         escalate to ``PermanentDeviceFault``; flow-attributable call sites
-        pass ``rid`` so a targeted fault quarantines only that flow."""
-        if self._faults is not None:
-            for _ in range(self._fault_retry_max + 1):
-                try:
-                    self._faults.check("device", req_id=rid, stage=stage)
-                    break
-                except TransientDeviceFault:
-                    self.device_fault_retries += 1
-            else:
-                raise PermanentDeviceFault(
-                    f"transient device fault at {stage} persisted past "
-                    f"{self._fault_retry_max} segment replays")
-        return fn(*args)
+        pass ``rid`` so a targeted fault quarantines only that flow.
+
+        The whole call is the span ``backend.launch``; a program's first
+        launch (trace, compile or compile-cache load) nests the span
+        ``backend.compile``, which carries the program's jit key."""
+        key, meta = self._programs.get(fn, (None, {}))
+        if rid is not None:
+            meta = {**meta, "rid": rid}
+        with self.phases("backend.launch", "host_launch_s", **meta):
+            if self._faults is not None:
+                for _ in range(self._fault_retry_max + 1):
+                    try:
+                        self._faults.check("device", req_id=rid, stage=stage)
+                        break
+                    except TransientDeviceFault:
+                        self.device_fault_retries += 1
+                else:
+                    raise PermanentDeviceFault(
+                        f"transient device fault at {stage} persisted past "
+                        f"{self._fault_retry_max} segment replays")
+            if fn in self._launched:
+                return fn(*args)
+            self._launched.add(fn)
+            with self.phases("backend.compile", "host_compile_s",
+                             key=repr(key)):
+                return fn(*args)
 
     def _extend_fn(self, c: int):
         from repro.models import extend
@@ -929,7 +989,8 @@ class JaxRealBackend(ExecutionBackend):
         self.kv_bytes_prefill += n * self._kv_token_bytes
         self.prefill_forward_tokens += n
         if pos >= req.prompt_len:  # last chunk -> first output token
-            self._first[rid] = int(nxt)
+            with self.phases("backend.fetch", "host_fetch_s", rid=rid):
+                self._first[rid] = int(nxt)
             self.host_syncs += 1
             self.prefill_host_syncs += 1
 
@@ -1074,8 +1135,17 @@ class JaxRealBackend(ExecutionBackend):
             raise NotImplementedError(self._ENC_DEC_MSG)
         if on_token is not None:
             self._on_token[req.id] = on_token
+        if req.submit_wall is not None:
+            self._waiting[req.id] = req.submit_wall
         if self.in_pool_prefill and req.tokens is not None:
             self._upload_prompt(req)
+
+    def _begin_prefill(self, req: Request) -> None:
+        """A flow's first prefill chunk launches: count its queue wait."""
+        t = self._waiting.pop(req.id, None)
+        if t is not None:
+            self.queue_wait_s[req.priority] += time.perf_counter() - t
+            self.queue_waits[req.priority] += 1
 
     # -- per-flow fault isolation (DESIGN.md §12) -----------------------------
     def _record_flow_fault(self, req: Request, exc: BaseException,
@@ -1119,6 +1189,7 @@ class JaxRealBackend(ExecutionBackend):
                       now: float) -> None:
         if req.tokens is None or req.id in self._quarantined:
             return
+        self._begin_prefill(req)
         self._track_prefill(req.id)
         try:
             if self.in_pool_prefill:
@@ -1156,7 +1227,8 @@ class JaxRealBackend(ExecutionBackend):
                 return
             # the last chunk's ``emit`` program already committed the first
             # token to the device token vector; fetch it once for streaming
-            first = int(nxt)
+            with self.phases("backend.fetch", "host_fetch_s", rid=rid):
+                first = int(nxt)
             self.host_syncs += 1
             self.prefill_host_syncs += 1
             self._row_pos.pop(rid, None)
@@ -1191,9 +1263,7 @@ class JaxRealBackend(ExecutionBackend):
                 self._set_source(n, ("slot", slot))
             for n in evicted:
                 self._set_source(n, None)
-        self._last[rid] = first
-        self._texts[rid] = [first]
-        self._emit(req, first)
+        self._commit_first(req, first)
 
     # -- decode ---------------------------------------------------------------
     def decode_run(self, reqs: List[Request], n_steps: int,
@@ -1246,12 +1316,23 @@ class JaxRealBackend(ExecutionBackend):
 
     def _account_decode(self, slots: List[int], n: int, rows: int, kvl: int):
         """Advance host-tracked row positions past an ``n``-step dispatch
-        and fold its extent into the elastic counters."""
+        and fold its extent into the elastic counters: each of its steps
+        moves ``rows x kvl`` KV positions, of which a live row at position
+        ``p`` holds ``p + 1`` (all ``kvl`` once its ring has wrapped)."""
+        live = 0
         for s in slots:
-            if s in self._slot_pos:
-                self._slot_pos[s] += n
+            p = self._slot_pos.get(s)
+            if p is None:
+                continue
+            if p + n <= kvl:  # steps read p + 1 .. p + n positions
+                live += n * (p + 1) + n * (n - 1) // 2
+            else:
+                live += sum(min(p + j + 1, kvl) for j in range(n))
+            self._slot_pos[s] = p + n
         self.decode_rows, self.decode_kv_limit = rows, kvl
         self.kv_bytes_decode += n * rows * kvl * self._kv_token_bytes
+        self.decode_kv_positions += n * rows * kvl
+        self.decode_kv_positions_live += live
 
     def _run_segment(self) -> None:
         """Launch the next bounded ``lax.scan`` segment of the committed run
@@ -1262,13 +1343,14 @@ class JaxRealBackend(ExecutionBackend):
             return
         slots = sorted(self._fused_slots)
         # contention observability (§6.4): register the segment with the
-        # pressure estimator and bucket its wall time (launch -> token
-        # block on host) by whether a prefill overlapped it
+        # pressure estimator and bucket its wall time (its launches and
+        # the fetch of its token block, from the phase clocks) by whether
+        # a prefill overlapped it
         co_executed = bool(self._prefill_live)
         self._pressure_est.add("decode", self.decode_bw_util)
         self.contention_pressure_peak = max(
             self.contention_pressure_peak, self._pressure_est.pressure)
-        t0 = time.perf_counter()
+        clocks_before = self.phases.total(_SEGMENT_CLOCKS)
         blocks = []
         for b in _pow2_buckets(n):
             rows, kvl = self._elastic_extent(slots, b)
@@ -1279,9 +1361,11 @@ class JaxRealBackend(ExecutionBackend):
             self.decode_device_calls += 1
             self._account_decode(slots, b, rows, kvl)
             blocks.append(block)
-        full = self._np.asarray(self._jnp.concatenate(blocks, axis=0)
-                                if len(blocks) > 1 else blocks[0])
-        seg_wall = time.perf_counter() - t0
+        with self.phases("backend.fetch", "host_fetch_s", rows=len(slots),
+                         steps=n):
+            full = self._np.asarray(self._jnp.concatenate(blocks, axis=0)
+                                    if len(blocks) > 1 else blocks[0])
+        seg_wall = self.phases.total(_SEGMENT_CLOCKS) - clocks_before
         self._pressure_est.remove("decode")
         if co_executed:
             self.co_executed_segments += 1
@@ -1340,7 +1424,8 @@ class JaxRealBackend(ExecutionBackend):
             fn, self.params, self._pool, toks, mask, stage="decode")
         self.decode_device_calls += 1
         self._account_decode(slots, 1, rows, kvl)
-        nxt = self._np.asarray(nxt)
+        with self.phases("backend.fetch", "host_fetch_s", rows=len(live)):
+            nxt = self._np.asarray(nxt)
         self.host_syncs += 1
         self._commit(live, nxt)
 
@@ -1359,12 +1444,20 @@ class JaxRealBackend(ExecutionBackend):
             self._fused_slots = None  # plan fully executed AND replayed
         self._commit(live, row)
 
+    def _commit_first(self, req: Request, first: int):
+        with self.phases("backend.commit", "host_commit_s", tokens=1):
+            self._last[req.id] = first
+            self._texts[req.id] = [first]
+            self._emit(req, first)
+
     def _commit(self, live: List[Request], tokens_by_slot):
-        for r in live:
-            t = int(tokens_by_slot[self._slot[r.id]])
-            self._last[r.id] = t
-            self._texts[r.id].append(t)
-            self._emit(r, t)
+        with self.phases("backend.commit", "host_commit_s",
+                         tokens=len(live)):
+            for r in live:
+                t = int(tokens_by_slot[self._slot[r.id]])
+                self._last[r.id] = t
+                self._texts[r.id].append(t)
+                self._emit(r, t)
 
     def _drop_flow_state(self, rid: int) -> None:
         """Free one flow's slot and host bookkeeping — shared by ``finish``
@@ -1391,6 +1484,7 @@ class JaxRealBackend(ExecutionBackend):
             self._slot_pos.pop(slot, None)
             heapq.heappush(self._free, slot)
         self._untrack_prefill(rid)
+        self._waiting.pop(rid, None)
         self._last.pop(rid, None)
         self._scratch.pop(rid, None)
         self._scratch_pos.pop(rid, None)
@@ -1606,6 +1700,14 @@ class JaxRealBackend(ExecutionBackend):
                 "decode_rows": self.decode_rows,
                 "decode_kv_limit": self.decode_kv_limit,
                 "kv_bytes_decode": self.kv_bytes_decode,
+                "decode_kv_positions": self.decode_kv_positions,
+                "decode_kv_positions_live": self.decode_kv_positions_live,
+                "queue_wait_s_reactive": self.queue_wait_s[Priority.REACTIVE],
+                "queue_wait_s_proactive":
+                    self.queue_wait_s[Priority.PROACTIVE],
+                "queue_waits_reactive": self.queue_waits[Priority.REACTIVE],
+                "queue_waits_proactive": self.queue_waits[Priority.PROACTIVE],
+                **self.phases.stats(),
                 "pool_slots": self.pool_slots,
                 # bounded-resource failure model (DESIGN.md §12)
                 "pool_slots_max": self.pool_slots_max,
@@ -1912,6 +2014,7 @@ class DualDeviceBackend(JaxRealBackend):
                       now: float) -> None:
         if req.tokens is None or req.id in self._quarantined:
             return
+        self._begin_prefill(req)
         if not self._stage_for(req, seq_start):
             super().prefill_chunk(req, seq_start, tokens, now)
             return
@@ -1966,7 +2069,8 @@ class DualDeviceBackend(JaxRealBackend):
         self.kv_bytes_handoff += cap * self._kv_token_bytes
         # the ONE host sync of this prefill: waits on the prefill device's
         # dependency chain only (decode segments keep their own queue)
-        first = int(nxt)
+        with self.phases("backend.fetch", "host_fetch_s", rid=rid):
+            first = int(nxt)
         self.host_syncs += 1
         self.prefill_host_syncs += 1
         self._slot_pos[slot] = req.prompt_len
@@ -1979,9 +2083,7 @@ class DualDeviceBackend(JaxRealBackend):
                 self._set_source(node, ("slot", slot))
             for node in evicted:
                 self._set_source(node, None)
-        self._last[rid] = first
-        self._texts[rid] = [first]
-        self._emit(req, first)
+        self._commit_first(req, first)
 
     def _drop_flow_state(self, rid: int) -> None:
         # mid-prefill abort / quarantine / release of a staged flow: the

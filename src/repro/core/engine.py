@@ -15,6 +15,7 @@ drives two device submeshes (DESIGN.md §2).
 from __future__ import annotations
 
 import os
+import time
 from typing import List, Optional
 
 from repro.configs.base import ModelConfig
@@ -75,9 +76,11 @@ class AgentXPUEngine:
         arrival = self._arrival_poll
         strict = self._strict_invariants
         backend = sched.backend
+        phases = backend.phases
 
         def poll(now: float):
-            sched.on_turn(now)
+            with phases("sched.turn", "host_sched_s"):
+                sched.on_turn(now)
             if strict:
                 backend.validate(strict=True)
             if arrival is not None:
@@ -212,6 +215,8 @@ class RealAgentXPUEngine(AgentXPUEngine):
         if req.deadline is None and self.deadline_s is not None \
                 and req.priority == Priority.REACTIVE:
             req.deadline = self.deadline_s
+        if req.submit_wall is None:
+            req.submit_wall = time.perf_counter()
         self.backend.register(req, on_token)
         if self._sim is not None:
             req.arrival_time = max(req.arrival_time, self._sim.now)

@@ -54,6 +54,10 @@ class Request:
     # optional SLO deadline in seconds RELATIVE to arrival: an expired flow
     # is aborted at the next segment boundary with TIMED_OUT (DESIGN.md §12)
     deadline: Optional[float] = None
+    # host wall clock (``time.perf_counter``) at submission; the backend
+    # counts each flow's wait from it to its first prefill launch
+    submit_wall: Optional[float] = dataclasses.field(default=None,
+                                                     compare=False)
     # -- runtime bookkeeping ------------------------------------------------
     state: ReqState = ReqState.QUEUED
     fault: Optional[str] = None  # cause of FAILED/TIMED_OUT/REJECTED

@@ -156,36 +156,51 @@ class Simulator:
     def run(self) -> SimMetrics:
         for req in self.requests:
             self._push(req.arrival_time, "arrival", req)
+        # spans and phase clocks of a backend that runs on a device (none
+        # for the simulation-only backend): each turn, and each scheduler
+        # callback inside it (core/tracing.py)
+        phases = self.sched.backend.phases
         while self.now < self.max_time:
-            if self.poll is not None:
-                self.poll(self.now)  # may inject() new arrivals
-            if not self._heap:
-                # the poll may have freed capacity (quarantine, deadline
-                # abort) and drained the admission wait queue: give the
-                # scheduler one dispatch chance before declaring the run
-                # over, else an admitted-at-drain flow would stall forever
-                if self.sched.next_dispatch(self.now):
+            with phases("engine.turn", "host_loop_s"):
+                if self.poll is not None:
+                    self.poll(self.now)  # may inject() new arrivals
+                if not self._heap:
+                    # the poll may have freed capacity (quarantine,
+                    # deadline abort) and drained the admission wait
+                    # queue: give the scheduler one dispatch chance before
+                    # declaring the run over, else an admitted-at-drain
+                    # flow would stall forever
+                    with phases("sched.dispatch", "host_sched_s"):
+                        started = self.sched.next_dispatch(self.now)
+                    if started:
+                        self._schedule_completions()
+                        continue
+                    break
+                t, _, kind, payload = heapq.heappop(self._heap)
+                if kind == "done":
+                    ln, epoch = payload
+                    if epoch != self._epoch[ln]:
+                        continue  # stale completion (rates changed)
+                    rk = self.sched.running.get(ln)
+                    if rk is None:
+                        continue
+                    self._advance(t)
+                    if rk.remaining > 1e-9:
+                        self._schedule_completions()
+                        continue
+                    meta = {} if rk.is_decode_batch \
+                        else {"rid": rk.req_ids[0]}
+                    with phases("sched.complete", "host_sched_s", **meta):
+                        self.sched.on_complete(rk, self.now)
+                else:
+                    self._advance(t)
+                    with phases("sched.arrival", "host_sched_s",
+                                rid=payload.id,
+                                priority=int(payload.priority)):
+                        self.sched.on_arrival(payload, self.now)
+                with phases("sched.dispatch", "host_sched_s"):
+                    started = self.sched.next_dispatch(self.now)
+                if started or kind == "done":
                     self._schedule_completions()
-                    continue
-                break
-            t, _, kind, payload = heapq.heappop(self._heap)
-            if kind == "done":
-                ln, epoch = payload
-                if epoch != self._epoch[ln]:
-                    continue  # stale completion (rates changed)
-                rk = self.sched.running.get(ln)
-                if rk is None:
-                    continue
-                self._advance(t)
-                if rk.remaining > 1e-9:
-                    self._schedule_completions()
-                    continue
-                self.sched.on_complete(rk, self.now)
-            else:
-                self._advance(t)
-                self.sched.on_arrival(payload, self.now)
-            started = self.sched.next_dispatch(self.now)
-            if started or kind == "done":
-                self._schedule_completions()
         return SimMetrics(completed=self.sched.done, sim_time=self.now,
                           energy_j=self.energy, lane_busy=self.lane_busy)

@@ -303,7 +303,7 @@ class ServingFrontend:
                           deadline=deadline, tokens=toks)
             h = FlowHandle(req, max_buffered_tokens=self.max_buffered_tokens,
                            frontend=self)
-            h.submit_wall = time.perf_counter()
+            h.submit_wall = req.submit_wall = time.perf_counter()
             self._flows[flow_id] = h
             (self._inbox_rt if priority == Priority.REACTIVE
              else self._inbox_be).append(h)
@@ -395,12 +395,16 @@ class ServingFrontend:
         """Engine arrival-source seam: runs once per event-loop turn (i.e.
         between abortable decode segments).  Hands over newly inboxed
         flows, drives queued cancels, and seals freshly retired handles so
-        consumers unblock within one segment of their flow ending."""
-        self._drive_cancels()
-        for h in list(self._inflight.values()):
-            self._seal_if_terminal(h)
-        with self._lock:
-            fresh = self._pop_arrivals_locked()
+        consumers unblock within one segment of their flow ending.  All of
+        it is the span ``frontend.arrivals`` (core/tracing.py)."""
+        with self.engine.backend.phases("frontend.arrivals",
+                                        "host_poll_s") as span:
+            self._drive_cancels()
+            for h in list(self._inflight.values()):
+                self._seal_if_terminal(h)
+            with self._lock:
+                fresh = self._pop_arrivals_locked()
+            span.note(flows=len(fresh))
         return [(h.req, self._on_token) for h in fresh]
 
     def _serve_loop(self) -> None:

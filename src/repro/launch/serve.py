@@ -19,6 +19,7 @@ from repro.configs import get_config, get_tiny_config
 from repro.core import (AgentXPUEngine, WorkloadConfig, generate_workload)
 from repro.core.annotation import PROFILES
 from repro.core.engine import RealAgentXPUEngine
+from repro.core.tracing import GC_KEY, PHASE_KEYS
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -242,6 +243,22 @@ def main(argv=None):
                   f"{st['decode_rows']}/{st['pool_slots']} rows x "
                   f"kv_limit {st['decode_kv_limit']}/{max_len}, "
                   f"{st['kv_bytes_decode']} KV bytes streamed")
+            fill = st["decode_kv_positions_live"] \
+                / max(st["decode_kv_positions"], 1)
+            print(f"[real] decode KV fill: {100 * fill:.1f}% of the "
+                  f"{st['decode_kv_positions']} KV positions decode steps "
+                  f"moved were live")
+            waits = []
+            for prio in ("reactive", "proactive"):
+                n = st[f"queue_waits_{prio}"]
+                mean_ms = 1e3 * st[f"queue_wait_s_{prio}"] / max(n, 1)
+                waits.append(f"{prio} {mean_ms:.1f} ms mean over {n} flows"
+                             if n else f"{prio} none")
+            print(f"[real] queue wait, submit to first prefill launch: "
+                  f"{', '.join(waits)}")
+            print("[real] engine thread, self seconds by phase: " + ", ".join(
+                f"{k[len('host_'):-len('_s')]} {st[k]:.3f}"
+                for k in PHASE_KEYS + (GC_KEY,)) + " (gc on any thread)")
             print(f"[real] kv pool: dtype {st['kv_dtype']}, "
                   f"kernel backend {st['kernel_backend']}, "
                   f"{st['quant_scale_bytes']} quant scale bytes")

@@ -47,6 +47,7 @@ SHARDS: Dict[str, List[str]] = {
         "test_models_smoke.py",
         "test_shards.py",
         "test_dual_device.py",
+        "test_tracing.py",
     ],
 }
 
