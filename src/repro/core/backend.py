@@ -288,6 +288,32 @@ def _launch_meta(key: tuple) -> dict:
     return {"kind": kind}
 
 
+def decode_program(cfg, kernel_backend: str, max_len: int, pool_size: int,
+                   rows: int, kv_limit: int, n_steps: Optional[int] = None):
+    """The body of a pooled decode program as the backend jits it, with the
+    pool and the token vector donated (arguments 1 and 2):
+    ``fn(params, pool, toks, mask)`` runs one ``decode_step`` (``n_steps``
+    None) or an ``n_steps`` ``decode_run`` over pool rows ``[0, rows)``,
+    reading ring windows of ``kv_limit`` slots from the whole pool, and
+    returns ``(next tokens | token block, token vector, pool)``.  Built
+    from the configuration alone, so a compile check can build it from
+    shapes."""
+    import jax.numpy as jnp
+    from repro.models import decode_run, decode_step
+    kw = dict(kv_limit=kv_limit, full_alloc=max_len,
+              kernel_backend=kernel_backend)
+
+    def fn(params, pool, toks, mask):
+        t, m = toks[:rows], mask[:rows]
+        if n_steps is None:
+            out, _, pool = decode_step(cfg, params, pool, t, m, **kw)
+            t = jnp.where(m, out, t)
+        else:
+            out, t, pool = decode_run(cfg, params, pool, t, m, n_steps, **kw)
+        return out, (toks.at[:rows].set(t) if rows < pool_size else t), pool
+    return fn
+
+
 # phase clocks that time a decode segment: its launches (with any first
 # compile) and the fetch of its token block
 _SEGMENT_CLOCKS = ("host_launch_s", "host_compile_s", "host_fetch_s")
@@ -514,11 +540,14 @@ class JaxRealBackend(ExecutionBackend):
         self.decode_rows = 0
         self.decode_kv_limit = 0
         self.kv_bytes_decode = 0
-        # cumulative over launched decode steps: KV positions each step
-        # reads and writes back (rows x kv_limit), and of those the
-        # positions live rows hold (pos + 1 per dispatched row)
+        # cumulative over launched decode steps: KV positions each step's
+        # attention reads (rows x kv_limit), and of those the positions
+        # live rows hold (pos + 1 per dispatched row)
         self.decode_kv_positions = 0
         self.decode_kv_positions_live = 0
+        # cumulative over launched decode steps: KV bytes the steps' in-place
+        # scatters write (active rows x one position's K/V of every layer)
+        self.decode_kv_bytes_written = 0
         # queue wait: wall seconds from a flow's submit to the launch of
         # its first prefill chunk, and how many flows began prefill, by
         # priority; _waiting holds the submit instants of flows not begun
@@ -642,37 +671,20 @@ class JaxRealBackend(ExecutionBackend):
     def _decode_fn(self, pool_size: int, rows: Optional[int] = None,
                    kv_limit: Optional[int] = None):
         """One masked decode iteration, elastic in both axes (DESIGN.md §9):
-        the program computes only the leading ``rows`` pool rows (static
-        slice — every live slot sits below the pow-2 row bound because the
-        free list prefers low slots) over a ``kv_limit``-bounded ring view,
-        then writes the advanced prefix back in place on the donated pool
-        (``kvcache.write_rows_prefix``).  ``rows == pool`` and ``kv_limit ==
-        max_len`` reproduce the full-pool program bit-for-bit (the
-        ``elastic_decode=False`` / ring-wrap fallback path)."""
-        from repro.models import decode_step, slice_rows, write_rows_prefix
-        cfg = self.cfg
-        jnp = self._jnp
+        the program computes only the leading ``rows`` pool rows (every live
+        slot sits below the pow-2 row bound because the free list prefers
+        low slots) and reads a ``kv_limit``-bounded window of each ring,
+        while each layer writes its new K/V in place into the donated pool
+        (``decode_program``).  ``rows == pool`` and ``kv_limit == max_len``
+        are the full-pool program (the ``elastic_decode=False`` / ring-wrap
+        fallback path)."""
         rows = pool_size if rows is None else rows
         kvl = self.max_len if kv_limit is None else kv_limit
-        max_len = self.max_len
-
-        def build():
-            def fn(params, pool, toks, mask):
-                sub = slice_rows(pool, rows) if rows < pool_size else pool
-                nxt, _, sub = decode_step(cfg, params, sub, toks[:rows],
-                                          mask[:rows], kv_limit=kvl,
-                                          full_alloc=max_len,
-                                          kernel_backend=self.kernel_backend)
-                new_t = jnp.where(mask[:rows], nxt, toks[:rows])
-                if rows < pool_size:
-                    pool = write_rows_prefix(pool, sub, rows, kvl, max_len)
-                    toks = toks.at[:rows].set(new_t)
-                else:
-                    pool, toks = sub, new_t
-                return nxt, toks, pool
-            return fn
-        return self._jitted(("decode", pool_size, rows, kvl), build,
-                            donate=(1, 2))
+        return self._jitted(
+            ("decode", pool_size, rows, kvl),
+            lambda: decode_program(self.cfg, self.kernel_backend,
+                                   self.max_len, pool_size, rows, kvl),
+            donate=(1, 2))
 
     def _decode_run_fn(self, pool_size: int, n_steps: int,
                        rows: Optional[int] = None,
@@ -680,29 +692,15 @@ class JaxRealBackend(ExecutionBackend):
         """``n_steps`` fused iterations with the same two-axis elasticity as
         :meth:`_decode_fn`; the caller's ``kv_limit`` covers the run's END
         (``next_pow2(max live pos + n_steps)``) so every position written
-        mid-scan stays inside the bounded view."""
-        from repro.models import decode_run, slice_rows, write_rows_prefix
-        cfg = self.cfg
+        mid-scan stays inside the bounded window."""
         rows = pool_size if rows is None else rows
         kvl = self.max_len if kv_limit is None else kv_limit
-        max_len = self.max_len
-
-        def build():
-            def fn(params, pool, toks, mask):
-                sub = slice_rows(pool, rows) if rows < pool_size else pool
-                block, t, sub = decode_run(cfg, params, sub, toks[:rows],
-                                           mask[:rows], n_steps,
-                                           kv_limit=kvl, full_alloc=max_len,
-                                           kernel_backend=self.kernel_backend)
-                if rows < pool_size:
-                    pool = write_rows_prefix(pool, sub, rows, kvl, max_len)
-                    toks = toks.at[:rows].set(t)
-                else:
-                    pool, toks = sub, t
-                return block, toks, pool
-            return fn
-        return self._jitted(("decode_run", pool_size, n_steps, rows, kvl),
-                            build, donate=(1, 2))
+        return self._jitted(
+            ("decode_run", pool_size, n_steps, rows, kvl),
+            lambda: decode_program(self.cfg, self.kernel_backend,
+                                   self.max_len, pool_size, rows, kvl,
+                                   n_steps),
+            donate=(1, 2))
 
     def _bind_fn(self, pool_size: int):
         from repro.models import write_slot
@@ -1317,8 +1315,9 @@ class JaxRealBackend(ExecutionBackend):
     def _account_decode(self, slots: List[int], n: int, rows: int, kvl: int):
         """Advance host-tracked row positions past an ``n``-step dispatch
         and fold its extent into the elastic counters: each of its steps
-        moves ``rows x kvl`` KV positions, of which a live row at position
-        ``p`` holds ``p + 1`` (all ``kvl`` once its ring has wrapped)."""
+        reads ``rows x kvl`` KV positions, of which a live row at position
+        ``p`` holds ``p + 1`` (all ``kvl`` once its ring has wrapped), and
+        writes one position's K/V of every layer for each dispatched row."""
         live = 0
         for s in slots:
             p = self._slot_pos.get(s)
@@ -1333,6 +1332,7 @@ class JaxRealBackend(ExecutionBackend):
         self.kv_bytes_decode += n * rows * kvl * self._kv_token_bytes
         self.decode_kv_positions += n * rows * kvl
         self.decode_kv_positions_live += live
+        self.decode_kv_bytes_written += n * len(slots) * self._kv_token_bytes
 
     def _run_segment(self) -> None:
         """Launch the next bounded ``lax.scan`` segment of the committed run
@@ -1702,6 +1702,7 @@ class JaxRealBackend(ExecutionBackend):
                 "kv_bytes_decode": self.kv_bytes_decode,
                 "decode_kv_positions": self.decode_kv_positions,
                 "decode_kv_positions_live": self.decode_kv_positions_live,
+                "decode_kv_bytes_written": self.decode_kv_bytes_written,
                 "queue_wait_s_reactive": self.queue_wait_s[Priority.REACTIVE],
                 "queue_wait_s_proactive":
                     self.queue_wait_s[Priority.PROACTIVE],

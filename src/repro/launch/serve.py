@@ -247,7 +247,8 @@ def main(argv=None):
                 / max(st["decode_kv_positions"], 1)
             print(f"[real] decode KV fill: {100 * fill:.1f}% of the "
                   f"{st['decode_kv_positions']} KV positions decode steps "
-                  f"moved were live")
+                  f"read were live; {st['decode_kv_bytes_written']} KV bytes "
+                  f"written in place")
             waits = []
             for prio in ("reactive", "proactive"):
                 n = st[f"queue_waits_{prio}"]

@@ -5,10 +5,9 @@ from repro.models.kvcache import (cache_bytes, copy_into_prefix,
                                   copy_prefix_rows, dequantize_kv,
                                   handoff_row, kv_supports_int8,
                                   paste_prefix, quantize_kv, read_row,
-                                  reset_row, select_rows, slice_rows,
-                                  snapshot_prefix, truncate_rings,
-                                  untruncate_rings, write_row_slice,
-                                  write_rows_prefix, write_slot)
+                                  reset_row, select_rows, snapshot_prefix,
+                                  truncate_rings, write_row_slice,
+                                  write_slot)
 from repro.models.params import (batch_pspec, cache_pspecs, param_pspecs,
                                  param_shardings)
 
@@ -17,7 +16,6 @@ __all__ = ["cache_bytes", "copy_into_prefix", "copy_prefix_rows",
            "extend_row", "forward", "handoff_row", "init_cache",
            "init_params", "kv_supports_int8", "layout", "paste_prefix",
            "prefill", "quantize_kv", "read_row", "reset_row", "select_rows",
-           "slice_rows", "snapshot_prefix", "truncate_rings",
-           "untruncate_rings", "write_row_slice", "write_rows_prefix",
+           "snapshot_prefix", "truncate_rings", "write_row_slice",
            "write_slot", "batch_pspec", "cache_pspecs", "param_pspecs",
            "param_shardings"]

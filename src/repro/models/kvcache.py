@@ -18,9 +18,9 @@ Quantized pools (DESIGN.md §11): ``kv_dtype="int8"`` stores the ``k``/``v``
 ring payload as symmetric int8 with per-(ring slot, kv head) float32 scales
 (``k_scale``/``v_scale``, shape (B, alloc, Hkv)).  The scale leaves carry the
 same ring axis as the payload, so every view/write helper in this module
-(truncate/untruncate, row slices, prefix copies) treats them as ordinary ring
-payload and the elastic-dispatch + prefix-cache machinery works unchanged on
-quantized pools.  Dequantization happens at the attention read
+(ring views, the decode step's in-place write, prefix copies) treats them as
+ordinary ring payload and the elastic-dispatch + prefix-cache machinery
+works unchanged on quantized pools.  Dequantization happens at the attention read
 (``transformer._attn_mix_extend`` or in-kernel in the Pallas backend), never
 as a separate pass.
 """
@@ -220,8 +220,9 @@ def truncate_rings(one, kv_limit, full):
 
     Batch-size agnostic: the alloc axis is addressed relative to the
     section layout (axis 1 for ``head``/``tail``, 2 for ``blocks``), so the
-    same view serves in-pool prefill (batch-1 rows, DESIGN.md §7) and
-    live-prefix-bounded decode over a slot pool (DESIGN.md §9)."""
+    same view serves in-pool prefill (batch-1 rows, DESIGN.md §7), prefix
+    snapshots and the staged-prefill handoff.  Pooled decode takes no view:
+    it reads the same extent from the carried pool (``write_decode_step``)."""
     from jax.tree_util import DictKey, tree_map_with_path
 
     if not full or kv_limit >= full:
@@ -239,77 +240,6 @@ def truncate_rings(one, kv_limit, full):
     for key in ("head", "tail"):
         out[key] = tree_map_with_path(fix(1), one[key])
     out["blocks"] = tree_map_with_path(fix(2), one["blocks"])
-    return out
-
-
-def untruncate_rings(full_cache, view, kv_limit, full):
-    """Inverse of :func:`truncate_rings`: write an advanced ``kv_limit``
-    view back over the first ``kv_limit`` ring slots of ``full_cache``.
-    Ring slots at and beyond ``kv_limit`` were provably untouched by the
-    bounded program (every live position stayed below the limit), so they
-    keep ``full_cache``'s buffers; non-ring leaves (positions, recurrent /
-    shift / conv state) are full-shape in the view and taken verbatim.
-    Under jit with ``full_cache`` donated the prefix write lowers to an
-    in-place dynamic-update-slice — O(kv_limit) bytes per ring leaf."""
-    from jax.tree_util import DictKey, tree_map_with_path
-
-    if not full or kv_limit >= full:
-        return view
-
-    def fix(axis):
-        def f(path, p, v):
-            name = path[-1].key if isinstance(path[-1], DictKey) else ""
-            if name in _RING_PAYLOAD and p.shape[axis] == full \
-                    and v.shape[axis] == kv_limit:
-                idx = (slice(None),) * axis + (slice(0, kv_limit),)
-                return p.at[idx].set(v)
-            return v
-        return f
-
-    out = dict(view)
-    for key in ("head", "tail"):
-        out[key] = tree_map_with_path(fix(1), full_cache[key], view[key])
-    out["blocks"] = tree_map_with_path(fix(2), full_cache["blocks"],
-                                       view["blocks"])
-    return out
-
-
-def slice_rows(pool, rows):
-    """Static leading-rows view of a pool cache (live-row sub-pool decode,
-    DESIGN.md §9): batch rows ``[0, rows)`` of every section.  With the
-    free list preferring low slots, ``rows = next_pow2(high_water + 1)``
-    covers every live request while a half-empty pool stops paying for its
-    dead rows' attention, MLP and recurrent-state math."""
-    return _map_batched(lambda p: p[:rows], lambda p: p[:, :rows], pool)
-
-
-def write_rows_prefix(pool, sub, rows, kv_limit, full):
-    """Write an advanced ``rows``-row sub-pool back into the leading rows
-    of the full pool, bounding ring traffic to the ``kv_limit`` prefix the
-    bounded program could have touched (``kv_limit >= full`` writes whole
-    rings — the ring-wrap fallback).  Rows at and beyond ``rows`` alias in
-    place under donation, exactly like the other prefix write-backs."""
-    from jax.tree_util import DictKey, tree_map_with_path
-
-    kv = None if (not full or kv_limit >= full) else kv_limit
-
-    def fix(axis):
-        def f(path, p, s):
-            name = path[-1].key if isinstance(path[-1], DictKey) else ""
-            row_idx = (slice(None),) * axis + (slice(0, rows),)
-            if kv is not None and name in _RING_PAYLOAD \
-                    and p.shape[axis + 1] == full:
-                idx = row_idx + (slice(0, kv),)
-                return p.at[idx].set(s[(slice(None),) * axis
-                                       + (slice(None), slice(0, kv))])
-            return p.at[row_idx].set(s)
-        return f
-
-    out = dict(pool)
-    out["pos"] = pool["pos"].at[:rows].set(sub["pos"])
-    for key in ("head", "tail"):
-        out[key] = tree_map_with_path(fix(0), pool[key], sub[key])
-    out["blocks"] = tree_map_with_path(fix(1), pool["blocks"], sub["blocks"])
     return out
 
 
@@ -448,13 +378,57 @@ def copy_into_prefix(new, old, p):
 
 
 def select_rows(mask, new, old):
-    """Masked cache update: row ``b`` of the result is ``new``'s where
-    ``mask[b]`` else ``old``'s — inactive slots of a pooled decode step keep
-    their state (KV ring buffers, recurrent states, positions) untouched."""
-    def sel(axis):
-        def f(n, o):
-            m = mask.reshape((1,) * axis + (-1,)
-                             + (1,) * (n.ndim - axis - 1))
-            return jnp.where(m, n, o)
-        return f
-    return _map_batched(sel(0), sel(1), new, old)
+    """Masked row update of per-row state (batch axis 0, as a pooled decode
+    step reads it): row ``b`` of the result is ``new``'s where ``mask[b]``
+    else ``old``'s, so inactive slots keep their positions and recurrent /
+    shift / conv states untouched.  Ring payload never goes through here:
+    :func:`write_decode_step` drops an inactive row's write instead."""
+    def sel(n, o):
+        return jnp.where(mask.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
+    return jax.tree_util.tree_map(sel, new, old)
+
+
+def write_decode_step(st, vals, pos, *, layer, active, kv_limit, full):
+    """In-place K/V write of one layer of a pooled decode step whose layer
+    loop carries the pool (DESIGN.md §9).
+
+    ``st`` holds the layer's ring leaves whole, as the loop carries them:
+    ``(P, alloc, ...)`` for an unrolled layer (``layer`` None) or
+    ``(R, P, alloc, ...)`` for a scanned group, ``layer`` being the traced
+    index into ``R``; other leaves (cross-attention K/V) arrive already cut
+    to the step's rows and pass through.  ``vals`` maps ring leaf names to
+    this step's ``(rows, 1, ...)`` values for pool rows ``[0, rows)`` and
+    ``pos`` is their ``(rows, 1)`` positions.  One scatter a leaf writes
+    them at ``(layer, row, pos % alloc)``; an inactive row's index points
+    past the pool and the scatter drops it, so a masked slot's ring is
+    never touched.  Under jit with the pool donated the scatter updates
+    the carried buffer in place: a step writes one position a row, not the
+    slab.
+
+    Returns ``(st, view)``: the updated leaves, and the window of each that
+    attention reads, sliced from the updated buffer so the step's own token
+    is in it: rows ``[0, rows)`` and, as in :func:`truncate_rings`, the
+    first ``kv_limit`` slots of a ring that cannot wrap (``alloc == full``)
+    or the whole of any other (``kv_limit >= full`` is the ring-wrap
+    fallback: an ordinary read extent, not a copy)."""
+    bounded = bool(full) and kv_limit is not None and kv_limit < full
+    rows = pos.shape[0]
+    lead = () if layer is None else (layer,)
+    out, view = dict(st), dict(st)
+    for name, leaf in st.items():
+        if name not in _RING_PAYLOAD:
+            continue
+        pool_rows, alloc = leaf.shape[len(lead)], leaf.shape[len(lead) + 1]
+        if name in vals:
+            row = jnp.where(active, jnp.arange(rows), pool_rows)
+            idx = lead + (row, pos[:, 0] % alloc)
+            leaf = leaf.at[idx].set(vals[name][:, 0], mode="drop")
+        out[name] = leaf
+        # one slice of exactly the window (not a layer slice sliced again),
+        # so XLA fuses it into the read that consumes it
+        ext = kv_limit if bounded and alloc == full else alloc
+        view[name] = jax.lax.dynamic_slice(
+            leaf, lead + (0,) * (leaf.ndim - len(lead)),
+            (1,) * len(lead) + (rows, ext) + leaf.shape[len(lead) + 2:]
+        ).reshape((rows, ext) + leaf.shape[len(lead) + 2:])
+    return out, view

@@ -11,6 +11,8 @@ Layer layout (mirrors params/cache pytrees):
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -288,24 +290,38 @@ def _attn_mix_train(cfg, lp, x, ctx):
     return jnp.einsum("bse,ed->bsd", out.reshape(B, S, Hq * hd), ap["wo"])
 
 
+def _store_kv(st, vals, pos, ctx):
+    """Write a chunk's ring values (K/V or latents, scales, positions) into
+    one layer's state.  Returns ``(state, view)``: the state to carry on,
+    and the ring window attention reads.
+
+    A pooled decode step (``ctx["pool_write"]``, see :func:`_decode_block`)
+    scatters into the carried pool in place and reads a window of it;
+    otherwise the chunk tail (the last ``min(C, alloc)`` tokens) lands at
+    ``pos % alloc`` of ``st`` itself, which attention then reads whole."""
+    if "pool_write" in ctx:
+        return ctx["pool_write"](st, vals, pos)
+    B, C = pos.shape
+    alloc = st["slot_pos"].shape[1]
+    n = min(C, alloc)
+    bidx = jnp.arange(B)[:, None]
+    slots = pos[:, C - n:] % alloc
+    st = dict(st, **{name: st[name].at[bidx, slots].set(v[:, C - n:])
+                     for name, v in vals.items()})
+    return st, st
+
+
 def _attn_mix_extend(cfg, lp, x, st, ctx):
     """Self-attention of a C-token chunk against the ring-buffer cache.
 
-    Writes the chunk's K/V into the cache first, then attends with position
-    masks; C == 1 uses the single-token decode kernels (incl. absorbed MLA).
+    Writes the chunk's K/V into the cache first (:func:`_store_kv`), then
+    attends with position masks; C == 1 uses the single-token decode
+    kernels (incl. absorbed MLA).
     """
     B, C, d = x.shape
     ap = lp["attn"]
     window = ctx.get("window") or cfg.sliding_window
     pos = ctx["pos0"][:, None] + jnp.arange(C)[None, :]  # (B, C) absolute
-    alloc = st["slot_pos"].shape[1]
-    bidx = jnp.arange(B)[:, None]
-
-    def write(buf, val):
-        # write the chunk tail (last min(C, alloc) tokens) at pos % alloc
-        n = min(C, alloc)
-        slots = (pos[:, C - n:] % alloc)
-        return buf.at[bidx, slots].set(val[:, C - n:])
 
     if cfg.use_mla:
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -317,26 +333,26 @@ def _attn_mix_extend(cfg, lp, x, st, ctx):
                         ap["kv_norm"], cfg.norm_eps)
         k_rope = apply_rope(jnp.einsum("bsd,dr->bsr", x, ap["w_krope"])
                             [:, :, None, :], pos, cfg.rope_theta)[:, :, 0, :]
-        st = dict(st, c=write(st["c"], c_kv), kr=write(st["kr"], k_rope),
-                  slot_pos=write(st["slot_pos"], pos))
+        st, kv = _store_kv(st, {"c": c_kv, "kr": k_rope, "slot_pos": pos},
+                           pos, ctx)
         if C == 1:
-            c_r = _constrain_cache_seq(st["c"], ctx)
-            kr_r = _constrain_cache_seq(st["kr"], ctx)
-            sp_r = _constrain_cache_seq(st["slot_pos"], ctx)
+            c_r = _constrain_cache_seq(kv["c"], ctx)
+            kr_r = _constrain_cache_seq(kv["kr"], ctx)
+            sp_r = _constrain_cache_seq(kv["slot_pos"], ctx)
             out = A.mla_decode_attention(q_nope[:, 0], q_rope[:, 0], c_r,
                                          kr_r, ap, sp_r,
                                          pos[:, 0], window=window)[:, None]
         else:
-            k_nope, vv = A.mla_expand_kv(st["c"], ap)
-            kr_b = jnp.broadcast_to(st["kr"][:, :, None, :],
-                                    (B, alloc, H, dr))
+            k_nope, vv = A.mla_expand_kv(kv["c"], ap)
+            kr_b = jnp.broadcast_to(kv["kr"][:, :, None, :],
+                                    (B, kv["kr"].shape[1], H, dr))
             qq = jnp.concatenate([q_nope, q_rope], -1)
             kk = jnp.concatenate([k_nope, kr_b], -1)
             if dv < dn + dr:
                 vv = jnp.pad(vv, ((0, 0), (0, 0), (0, 0), (0, dn + dr - dv)))
             out = chunked_attention(
                 qq, kk, vv, causal=True, window=window, pos_q=pos,
-                pos_kv=st["slot_pos"], q_chunk=ctx["q_chunk"],
+                pos_kv=kv["slot_pos"], q_chunk=ctx["q_chunk"],
                 kv_chunk=ctx["kv_chunk"])[..., :dv]
         y = jnp.einsum("bsD,Dd->bsd", out.reshape(B, C, H * dv), ap["wo"])
         return y, st
@@ -359,13 +375,10 @@ def _attn_mix_extend(cfg, lp, x, st, ctx):
     if quant:
         qk, ks = kvcache.quantize_kv(k)
         qv, vs = kvcache.quantize_kv(v)
-        st = dict(st, k=write(st["k"], qk), v=write(st["v"], qv),
-                  k_scale=write(st["k_scale"], ks),
-                  v_scale=write(st["v_scale"], vs),
-                  slot_pos=write(st["slot_pos"], pos))
+        vals = {"k": qk, "v": qv, "k_scale": ks, "v_scale": vs}
     else:
-        st = dict(st, k=write(st["k"], k), v=write(st["v"], v),
-                  slot_pos=write(st["slot_pos"], pos))
+        vals = {"k": k, "v": v}
+    st, kv = _store_kv(st, dict(vals, slot_pos=pos), pos, ctx)
     # Pallas hot path (kernel_backend="pallas"): same mask semantics as the
     # XLA reference, GQA done natively in-kernel.  The kernels are
     # single-device, so a sharded run must ask for the XLA path.
@@ -378,38 +391,38 @@ def _attn_mix_extend(cfg, lp, x, st, ctx):
         if pallas:
             from repro.kernels import ops as kops
             out = kops.decode_attention(
-                q[:, 0], st["k"], st["v"], st["slot_pos"], pos[:, 0],
-                window=window, k_scale=st.get("k_scale"),
-                v_scale=st.get("v_scale"))[:, None]
+                q[:, 0], kv["k"], kv["v"], kv["slot_pos"], pos[:, 0],
+                window=window, k_scale=kv.get("k_scale"),
+                v_scale=kv.get("v_scale"))[:, None]
         else:
-            k_r = _constrain_cache_seq(st["k"], ctx)
-            v_r = _constrain_cache_seq(st["v"], ctx)
-            sp_r = _constrain_cache_seq(st["slot_pos"], ctx)
+            k_r = _constrain_cache_seq(kv["k"], ctx)
+            v_r = _constrain_cache_seq(kv["v"], ctx)
+            sp_r = _constrain_cache_seq(kv["slot_pos"], ctx)
             if quant:
-                k_r = kvcache.dequantize_kv(k_r, st["k_scale"], k.dtype)
-                v_r = kvcache.dequantize_kv(v_r, st["v_scale"], v.dtype)
+                k_r = kvcache.dequantize_kv(k_r, kv["k_scale"], k.dtype)
+                v_r = kvcache.dequantize_kv(v_r, kv["v_scale"], v.dtype)
             out = decode_attention(q[:, 0], k_r, v_r, sp_r,
                                    pos[:, 0], window=window)[:, None]
     elif pallas:
         from repro.kernels import ops as kops
         out = kops.flash_attention_pool(
-            jnp.swapaxes(q, 1, 2), jnp.swapaxes(st["k"], 1, 2),
-            jnp.swapaxes(st["v"], 1, 2), pos, st["slot_pos"], window=window,
-            k_scale=jnp.swapaxes(st["k_scale"], 1, 2) if quant else None,
-            v_scale=jnp.swapaxes(st["v_scale"], 1, 2) if quant else None)
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(kv["k"], 1, 2),
+            jnp.swapaxes(kv["v"], 1, 2), pos, kv["slot_pos"], window=window,
+            k_scale=jnp.swapaxes(kv["k_scale"], 1, 2) if quant else None,
+            v_scale=jnp.swapaxes(kv["v_scale"], 1, 2) if quant else None)
         out = jnp.swapaxes(out, 1, 2)
     else:
-        kk, vv = st["k"], st["v"]
+        kk, vv = kv["k"], kv["v"]
         if quant:
-            kk = kvcache.dequantize_kv(kk, st["k_scale"], k.dtype)
-            vv = kvcache.dequantize_kv(vv, st["v_scale"], v.dtype)
+            kk = kvcache.dequantize_kv(kk, kv["k_scale"], k.dtype)
+            vv = kvcache.dequantize_kv(vv, kv["v_scale"], v.dtype)
         if ctx.get("tp_axis"):
             kk, vv = _expand_kv(kk, Hq // Hkv), _expand_kv(vv, Hq // Hkv)
             q = _constrain_heads(q, ctx)
             kk = _constrain_heads(kk, ctx)
             vv = _constrain_heads(vv, ctx)
         out = chunked_attention(q, kk, vv, causal=True, window=window,
-                                pos_q=pos, pos_kv=st["slot_pos"],
+                                pos_q=pos, pos_kv=kv["slot_pos"],
                                 q_chunk=ctx["q_chunk"], kv_chunk=ctx["kv_chunk"])
     y = jnp.einsum("bse,ed->bsd", out.reshape(B, C, Hq * hd), ap["wo"])
     return y, st
@@ -788,44 +801,119 @@ def extend_row(cfg, params, pool, tokens, slot, kv_limit=None,
                                            tokens.shape[1])
 
 
-def _decode_step_inner(cfg, params, cache, tokens, active, **kw):
-    """One masked decode iteration on whatever cache view it is handed —
-    the un-bounded core shared by :func:`decode_step` and the scan body of
-    :func:`decode_run` (which truncates once outside the scan)."""
-    logits, new_cache = extend(cfg, params, cache, tokens[:, None], **kw)
-    new_cache = kvcache.select_rows(active, new_cache, cache)
-    return logits.argmax(-1).astype(jnp.int32), logits, new_cache
+def _decode_block(cfg, kind, lp, x, st, layer, active, ctx):
+    """One block of a pooled decode step, against one layer's state as the
+    layer loop carries it (``layer``: traced index into a scanned group's
+    stacked leaves, None for an unrolled layer).
+
+    Ring payload stays whole: ``_attn_mix_extend`` writes this step's K/V
+    into it in place and reads its window (``kvcache.write_decode_step``,
+    through ``ctx["pool_write"]``).  Per-row state whose bytes do not grow
+    with the ring (recurrent ``wkv``/``h``, ``conv``/``shift_*``, cross-
+    attention ``xk``/``xv``) is read for rows ``[0, rows)``, advanced by the
+    same :func:`apply_block` prefill runs, and written back under the
+    active mask (``kvcache.select_rows``); a leaf the block hands back
+    unchanged is not written at all."""
+    rows = x.shape[0]
+
+    def take(a):
+        if layer is not None:
+            a = jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+        return a[:rows]
+
+    def put(a, v):
+        if layer is None:
+            return jax.lax.dynamic_update_slice(a, v, (0,) * a.ndim)
+        return jax.lax.dynamic_update_slice(a, v[None],
+                                            (layer,) + (0,) * (a.ndim - 1))
+
+    ring = {n: a for n, a in st.items() if n in kvcache._RING_PAYLOAD}
+    rest = {n: take(a) for n, a in st.items() if n not in ring}
+    write = functools.partial(
+        kvcache.write_decode_step, layer=layer, active=active,
+        kv_limit=ctx["kv_limit"], full=ctx["full_alloc"])
+    x, new, _ = apply_block(cfg, kind, lp, x, {**ring, **rest},
+                            dict(ctx, pool_write=write))
+    out = dict(st)
+    for n, v in new.items():
+        if n in ring:
+            out[n] = v
+        elif v is not rest[n]:
+            out[n] = put(st[n], kvcache.select_rows(active, v, rest[n]))
+    return x, out
+
+
+def _decode_trunk(cfg, params, x, pool, active, ctx):
+    """The layers of one pooled decode step with the whole pool as the loop
+    carry: head layers, a ``lax.scan`` over each scanned group's stacked
+    params and layer index (the pool in the carry, not in ``xs``/``ys``,
+    so its buffers are updated in place, never stacked anew), tail layers.
+    Rows ``[rows, P)`` of the pool are neither read nor written."""
+    head, pattern, repeats, tail = layout(cfg)
+
+    def layers(kinds, lps, states, x):
+        out = []
+        for kind, lp, st in zip(kinds, lps, states):
+            x, st = _decode_block(cfg, kind, lp, x, st, None, active, ctx)
+            x = _constrain(x, ctx)
+            out.append(st)
+        return x, tuple(out)
+
+    def body(carry, xs):
+        x, blocks = carry
+        gp, layer = xs
+        blocks = dict(blocks)
+        for pi, kind in enumerate(pattern):
+            x, blocks[str(pi)] = _decode_block(cfg, kind, gp[str(pi)], x,
+                                               blocks[str(pi)], layer,
+                                               active, ctx)
+            x = _constrain(x, ctx)
+        return (x, blocks), None
+
+    x = _constrain(x, ctx)
+    x, new_head = layers(head, params["head"], pool["head"], x)
+    (x, blocks), _ = jax.lax.scan(
+        body, (x, pool["blocks"]),
+        (params["blocks"], jnp.arange(repeats, dtype=jnp.int32)))
+    x, new_tail = layers(tail, params["tail"], pool["tail"], x)
+    return x, dict(pool, head=new_head, blocks=blocks, tail=new_tail)
 
 
 def decode_step(cfg, params, cache, tokens, active, kv_limit=None,
                 full_alloc=None, **kw):
     """One masked decode iteration over a slot-pool cache (DESIGN.md §3).
 
-    tokens: (B,) int32 last token per pool slot; active: (B,) bool slot mask.
-    All B rows are computed (static shape => one compiled kernel per pool
-    size), but cache rows with ``active == False`` are left untouched, so
-    unbound / not-dispatched slots neither corrupt their KV state nor advance
-    their position.  Returns (next_tokens (B,), logits (B, V), new_cache)
-    with greedy next tokens computed on-device.
+    tokens: (rows,) int32 last token of pool rows ``[0, rows)``; active:
+    (rows,) bool slot mask.  ``cache`` is the whole pool (``P >= rows``
+    rows; jit it donated): rows ``[rows, P)`` are not computed, and rows
+    with ``active == False`` are computed but leave no trace — their K/V,
+    positions and recurrent state stay bit-identical.  Returns
+    (next_tokens (rows,), logits (rows, V), new_cache) with greedy next
+    tokens computed on-device.
 
+    The pool is the carry of the step's layer loop: each layer writes its
+    one new K/V position a row in place (``kvcache.write_decode_step``) and
+    attention reads the layer's ``[:rows, :kv_limit]`` window of it.
     ``kv_limit`` (static, pow-2) bounds the live prefix of every ACTIVE row
     (mirroring :func:`extend_row`) and ``full_alloc`` is the cache's
-    build-time ``max_len``: attention runs on a ``kvcache.truncate_rings``
-    view scoring O(kv_limit) keys instead of O(alloc), then the advanced
-    prefix writes back in place (``kvcache.untruncate_rings``).  The caller
-    guarantees ``pos < kv_limit`` holds for every active row after the step
-    — a row that wrapped its ring (``pos >= full_alloc``) needs
-    ``kv_limit >= full_alloc``, which makes both bounds the identity
-    (exactness first).  Windowed leaves (``alloc < full_alloc``) always
-    keep their full (already small) ring.
+    build-time ``max_len``.  The caller guarantees ``pos < kv_limit`` for
+    every active row after the step — a row that wrapped its ring (``pos >=
+    full_alloc``) needs ``kv_limit >= full_alloc``, which reads whole rings.
+    Windowed leaves (``alloc < full_alloc``) are always read whole.
     """
-    view = cache if kv_limit is None else \
-        kvcache.truncate_rings(cache, kv_limit, full_alloc)
-    nxt, logits, view = _decode_step_inner(cfg, params, view, tokens,
-                                           active, **kw)
-    if kv_limit is not None:
-        view = kvcache.untruncate_rings(cache, view, kv_limit, full_alloc)
-    return nxt, logits, view
+    rows = tokens.shape[0]
+    pos = cache["pos"][:rows]
+    if "enc_out" in cache:
+        kw["enc_out"] = cache["enc_out"][:rows]
+    ctx = _default_ctx(cfg, "decode", pos0=pos, kv_limit=kv_limit,
+                       full_alloc=full_alloc, **kw)
+    x = embed_tokens(tokens[:, None], params["embed"]["w"])
+    x, cache = _decode_trunk(cfg, params, x, cache, active, ctx)
+    cache = dict(cache, pos=cache["pos"].at[:rows].set(
+        jnp.where(active, pos + 1, pos)))
+    x_last = rms_norm(x[:, -1, :], params["final_norm"]["w"], cfg.norm_eps)
+    logits = lm_logits(x_last, params)
+    return logits.argmax(-1).astype(jnp.int32), logits, cache
 
 
 def decode_run(cfg, params, cache, tokens, active, n_steps: int,
@@ -836,34 +924,27 @@ def decode_run(cfg, params, cache, tokens, active, n_steps: int,
     Between scheduler-visible events the decode batch is fixed, so there is
     no reason to return to Python per token: the scan keeps the KV pool, the
     per-slot last tokens and the greedy feedback loop on device and emits the
-    whole ``(n_steps, B)`` token block at the boundary.  Inactive slots are
-    masked exactly as in :func:`decode_step`, so a fused run is token-exact
-    against ``n_steps`` separate ``decode_step`` calls.
+    whole ``(n_steps, rows)`` token block at the boundary.  Each step is a
+    :func:`decode_step` with the pool carried through the scan, so a fused
+    run is token-exact against ``n_steps`` separate calls.
 
-    ``kv_limit``/``full_alloc`` bound the live prefix exactly as in
-    :func:`decode_step`, with the truncation hoisted OUT of the scan (one
-    view, one write-back for the whole run).  Positions advance ``n_steps``
-    times inside the scan, so the caller's bound must cover the run's END:
-    ``max live pos + n_steps <= kv_limit`` across the active rows.
+    Positions advance ``n_steps`` times inside the scan, so the caller's
+    ``kv_limit`` must cover the run's END: ``max live pos + n_steps <=
+    kv_limit`` across the active rows.
 
-    tokens: (B,) int32 last token per pool slot; active: (B,) bool.
-    Returns (token_block (n_steps, B), final_tokens (B,), new_cache).
+    tokens: (rows,) int32 last token of pool rows ``[0, rows)``; active:
+    (rows,) bool.  Returns (token_block (n_steps, rows), final_tokens
+    (rows,), new_cache).
     """
-    view = cache if kv_limit is None else \
-        kvcache.truncate_rings(cache, kv_limit, full_alloc)
-
     def body(carry, _):
-        view, toks = carry
-        nxt, _, view = _decode_step_inner(cfg, params, view, toks, active,
-                                          **kw)
-        toks = jnp.where(active, nxt, toks)
-        return (view, toks), nxt
+        pool, toks = carry
+        nxt, _, pool = decode_step(cfg, params, pool, toks, active,
+                                   kv_limit, full_alloc, **kw)
+        return (pool, jnp.where(active, nxt, toks)), nxt
 
-    (view, toks), block = jax.lax.scan(body, (view, tokens), None,
+    (pool, toks), block = jax.lax.scan(body, (cache, tokens), None,
                                        length=int(n_steps))
-    if kv_limit is not None:
-        view = kvcache.untruncate_rings(cache, view, kv_limit, full_alloc)
-    return block, toks, view
+    return block, toks, pool
 
 
 def prefill(cfg, params, tokens, *, max_len=None, window=None,

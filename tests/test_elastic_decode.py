@@ -7,6 +7,7 @@ while leaving the kernel-completion trace untouched (the backend changes
 import copy
 
 import numpy as np
+import pytest
 
 from repro.core import AgentXPUEngine, Priority, Request
 
@@ -189,3 +190,68 @@ def test_sim_trace_invariant_to_elasticity():
     assert len(m_sim.completed) == len(m_el.completed) == 3
     assert eng_sim.last_trace == eng.last_trace == eng_full.last_trace
     assert m_sim.sim_time == m_el.sim_time == m_full.sim_time
+
+
+@pytest.mark.parametrize("n_steps", [1, 8], ids=["decode", "decode_run"])
+@pytest.mark.parametrize("kv", ["bf16", "int8", "window_wrapped"])
+def test_masked_rows_state_bit_identical(kv, n_steps):
+    """Bound-but-inactive slots below the row bound are computed but leave
+    no trace: the pooled decode program's in-place scatter drops their
+    writes, so K, V, ``slot_pos`` and the int8 scales, their position and
+    recurrent state stay bit-identical, as do the rows past the bound —
+    while the active rows do advance."""
+    import jax
+    import jax.numpy as jnp
+    from jax.tree_util import DictKey, tree_flatten_with_path
+    from repro.configs import get_tiny_config
+    from repro.core.backend import decode_program
+    from repro.models import init_cache, init_params
+    arch = "recurrentgemma-9b" if kv == "window_wrapped" else "llama3-405b"
+    cfg = get_tiny_config(arch)
+    params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    pool_size, max_len, rows = 8, 64, 4
+    rng = np.random.default_rng(101)
+    if kv == "window_wrapped":
+        # window 32 < max_len: rings of 32 slots, every row past a wrap
+        pos = rng.integers(33, max_len - n_steps, pool_size)
+        kv_limit = max_len
+    else:
+        pos = rng.integers(0, 32 - n_steps, pool_size)
+        kv_limit = 32
+    active = np.array([True, False, True, False] + [False] * 4)
+    pool = init_cache(cfg, params, pool_size, max_len, jnp.float32,
+                      kv_dtype="int8" if kv == "int8" else None)
+
+    def fill(path, x):
+        name = path[-1].key if isinstance(path[-1], DictKey) else ""
+        if name == "pos":
+            return jnp.asarray(pos, jnp.int32)
+        if name == "slot_pos":  # the latest position held by each slot
+            s = np.arange(x.shape[-1])
+            held = s + (pos[:, None] - 1 - s) // x.shape[-1] * x.shape[-1]
+            return jnp.asarray(np.broadcast_to(np.where(held >= 0, held, -1),
+                                               x.shape), jnp.int32)
+        if x.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, x.shape), jnp.int8)
+        return jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+    pool = jax.tree_util.tree_map_with_path(fill, pool)
+    before, _ = tree_flatten_with_path(
+        jax.tree_util.tree_map(np.asarray, pool))
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, pool_size), jnp.int32)
+    fn = decode_program(cfg, "xla", max_len, pool_size, rows, kv_limit,
+                        None if n_steps == 1 else n_steps)
+    _, _, new = jax.jit(fn)(params, pool, toks, jnp.asarray(active))
+    after, _ = tree_flatten_with_path(new)
+    still = np.flatnonzero(~active)  # rows 1 and 3, and 4.. past the bound
+    names = set()
+    for (path, old), (_, cur) in zip(before, after):
+        axis = 1 if path[0].key == "blocks" else 0
+        names.add(path[-1].key)
+        np.testing.assert_array_equal(np.take(np.asarray(cur), still, axis),
+                                      np.take(old, still, axis),
+                                      err_msg=jax.tree_util.keystr(path))
+    assert {"k", "v", "slot_pos"} <= names
+    assert kv != "int8" or {"k_scale", "v_scale"} <= names
+    assert kv != "window_wrapped" or {"h", "conv"} <= names
+    np.testing.assert_array_equal(np.asarray(new["pos"]),
+                                  pos + n_steps * active)

@@ -103,3 +103,122 @@ def test_flash_attention_pool_compiles_for_v5e(one_chip, kv, B, ring, chunk,
                                     v_scale=vs, kv_limit=kv_limit,
                                     interpret=False)
     _compile(fn, q, k, v, pos_q, pos_kv, ks, vs)
+
+
+# -- the pooled decode programs, as the backend builds them ------------------
+# Published widths with the depth cut: Qwen2.5-3B (36 layers -> 4) on the
+# XLA path, one 8-layer stage of Qwen2.5-32B (-> 2) on the Pallas path; both
+# with the benchmark's ring of 4096 slots and its slot counts.
+DECODE_MODELS = {
+    "qwen2.5-3b": (dict(num_layers=4, d_model=2048, num_heads=16,
+                        num_kv_heads=2, d_ff=11008, vocab_size=151936,
+                        tie_embeddings=True), 16, "xla"),
+    "qwen2.5-32b-L8": (dict(num_layers=2, d_model=5120, num_heads=40,
+                            num_kv_heads=8, d_ff=27648, vocab_size=152064,
+                            tie_embeddings=False), 12, "pallas"),
+}
+DECODE_RING = 4096
+
+
+def _hlo_outputs(text):
+    """(opcode, dtype, shape, root opcode of a fusion's computation) of
+    every instruction outside fused computations: the buffers the program
+    materialises."""
+    import re
+    comps, roots, cur = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+            name = head.group(1)
+            continue
+        if cur is not None and line.startswith("  "):
+            cur.append(line)
+            root = re.match(r"\s+ROOT %\S+ = \S+ ([\w\-]+)\(", line)
+            if root:
+                roots[name] = root.group(1)
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+    out = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = re.match(r"\s+(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\{[^}]*\} "
+                         r"([\w\-]+)\(", line)
+            if not m:
+                continue
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            out.append((m.group(3), m.group(1),
+                        tuple(int(d) for d in m.group(2).split(",") if d),
+                        roots.get(called.group(1)) if called else None))
+    return out
+
+
+@pytest.mark.parametrize("n_steps", [None, 8], ids=["decode", "decode_run"])
+@pytest.mark.parametrize("rows,kv_limit", [(None, 2048), (None, 4096),
+                                           (8, 1024)])
+@pytest.mark.parametrize("model", sorted(DECODE_MODELS))
+def test_pooled_decode_writes_kv_in_place_for_v5e(one_chip, monkeypatch,
+                                                  model, rows, kv_limit,
+                                                  n_steps):
+    """The decode programs keep the pool as their loop carry: the only ops
+    that output a stacked ring leaf ``[layers, rows|pool, kv_limit|ring,
+    ...]`` are the one in-place scatter per leaf, and the temporaries stay
+    far below the pool's ring bytes (the parent's slab views took more than
+    the ring itself)."""
+    from repro.configs.base import ModelConfig
+    from repro.core.backend import decode_program
+    from repro.kernels import ops as kops
+    from repro.models import init_cache, init_params
+    # compile the Mosaic kernels, as on the chip (CPU interprets them)
+    monkeypatch.setattr(kops, "_interpret_default", lambda: False)
+    widths, pool_size, kb = DECODE_MODELS[model]
+    rows = rows or pool_size
+    cfg = ModelConfig(name=model, arch_type="dense", head_dim=HD,
+                      rope_theta=1e6, qkv_bias=True, norm_eps=1e-6, **widths)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    pool = on_chip(jax.eval_shape(
+        lambda: init_cache(cfg, None, pool_size, DECODE_RING, jnp.bfloat16)))
+    toks = _spec((pool_size,), jnp.int32, one_chip)
+    mask = _spec((pool_size,), jnp.bool_, one_chip)
+    fn = decode_program(cfg, kb, DECODE_RING, pool_size, rows, kv_limit,
+                        n_steps)
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, pool, toks, mask).compile()
+
+    ring_leaves = pool["blocks"]["0"]
+    ring_bytes = sum(x.size * x.dtype.itemsize for x in
+                     jax.tree_util.tree_leaves(ring_leaves))
+    L, hkv = widths["num_layers"], widths["num_kv_heads"]
+    slabs = {(L, r, e) + tail for r in (rows, pool_size)
+             for e in (kv_limit, DECODE_RING) for tail in ((), (hkv, HD))}
+    stacked = [(op, root) for op, dtype, shape, root
+               in _hlo_outputs(compiled.as_text()) if shape in slabs
+               and op not in ("parameter", "get-tuple-element", "tuple",
+                              "while", "bitcast")]
+    scatters = [op for op in stacked if op == ("fusion", "scatter")]
+    # memory-space transfers the compiler may schedule to stage the small
+    # int32 slot_pos leaf in VMEM around its scatter
+    staged = [op for op in stacked if op[0] in ("copy-done", "custom-call")]
+    assert len(scatters) == len(ring_leaves), stacked
+    assert len(stacked) == len(scatters) + len(staged), stacked
+    assert len(staged) <= 2 and (not staged or ring_leaves[
+        "slot_pos"].size * 4 < ring_bytes / 100), stacked
+
+    # what the kernel wrapper and the weights' layout add, neither of which
+    # this program changes: the Pallas kernel reads one layer's K and V
+    # window through a head-major copy, and a fused run may hoist layout
+    # copies of the q/k/v projections out of its step loop
+    allowance = 0
+    if kb == "pallas":
+        allowance += 2 * rows * kv_limit * hkv * HD * 2
+    if n_steps:
+        allowance += sum(params["blocks"]["0"]["attn"][w].size * 2
+                         for w in ("wq", "wk", "wv"))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < ring_bytes / 10 + allowance, (temp, ring_bytes, allowance)

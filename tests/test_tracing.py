@@ -20,6 +20,7 @@ from repro.launch.frontend import ServingFrontend  # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 COUNTERS = ("decode_kv_positions", "decode_kv_positions_live",
+            "decode_kv_bytes_written",
             "queue_wait_s_reactive", "queue_wait_s_proactive",
             "queue_waits_reactive", "queue_waits_proactive")
 
@@ -166,6 +167,38 @@ def test_kv_position_counters_match_a_hand_count(tiny):
     assert st["decode_kv_positions_live"] <= st["decode_kv_positions"]
 
 
+def test_kv_bytes_written_match_a_hand_count(tiny):
+    """Three flows in slots 0-2 (a row bound of 4): a fused run of 6 steps
+    in segments of 2 launches two segments, the third is cancelled
+    unlaunched and counts nothing, then one single-step decode follows.
+    Each launched step writes one position's K and V of every layer, and
+    its ``slot_pos``, for each of the three active rows, not the four
+    computed ones."""
+    cfg, params = tiny
+    be = JaxRealBackend(cfg, params, pool_slots=4, max_len=64,
+                        decode_segment_steps=2)
+    reqs = [Request(id=i, priority=Priority.PROACTIVE, prompt_len=n,
+                    max_new_tokens=12, arrival_time=0.0,
+                    tokens=_prompt(cfg, 70 + i, n))
+            for i, n in enumerate((5, 9, 7))]
+    for r in reqs:
+        be.register(r)
+        be.prefill_chunk(r, 0, r.prompt_len, 0.0)
+        be.prefill_done(r, 0.0)
+    be.decode_run(reqs, 6, 0.0)  # segment 1 launches
+    for _ in range(3):  # the third replay launches segment 2
+        be.decode_iteration(reqs, 0.0)
+    be.request_preempt(0.0)  # segment 3 never launches
+    be.decode_iteration(reqs, 0.0)  # segment 2's buffered row
+    assert be.stats()["decode_rows"] == 4
+    be.decode_iteration(reqs, 0.0)  # one single-step program
+    st = be.stats()
+    assert st["aborted_steps"] == 2 and st["decode_device_calls"] == 3
+    item = np.dtype(be.dtype).itemsize
+    token = cfg.num_layers * (2 * cfg.num_kv_heads * cfg.head_dim * item + 4)
+    assert st["decode_kv_bytes_written"] == (2 + 2 + 1) * 3 * token
+
+
 def test_queue_waits_count_flows_that_began_prefill(engine, tiny):
     cfg, _ = tiny
     st0 = engine.stats()
@@ -238,6 +271,7 @@ def test_launcher_reports_phases_waits_and_kv_fill(capsys, monkeypatch):
                 "--reactive-interval", "3"])
     out = capsys.readouterr().out
     assert "[real] decode KV fill: " in out
+    assert " KV bytes written in place" in out
     assert "[real] queue wait, submit to first prefill launch: " in out
     line = next(ln for ln in out.splitlines()
                 if ln.startswith("[real] engine thread, self seconds"))
