@@ -4,31 +4,29 @@ so the count is the same whatever implements it, and work the program
 skips (a cache hit) or repeats cannot raise it.
 
 A token at context length ``c`` (the keys it attends to, itself included)
-requires ``2 * matmul_params + 4 * c * heads * head_dim * layers`` FLOPs:
-every non-embedding weight once (the output head included, which a tied
-model reads from its embedding), plus QK^T and PV.
+requires ``2 * matmul_params + attn_flops(c)`` FLOPs; both terms and the
+KV bytes of a token are the configuration's family's
+(``bench/family.py``).
 """
 from __future__ import annotations
+
+from bench import family
 
 KV_BYTES = {"bf16": 2, "int8": 1}
 
 
 def matmul_params(cfg) -> int:
-    """Weights multiplied per token: every layer's projections and FFN
-    (biases and norm gains included) and the output head; the embedding
-    lookup is a gather, not a product."""
-    d, hq, hkv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    attn = d * hq * hd + 2 * d * hkv * hd + hq * hd * d
-    if cfg.qkv_bias:
-        attn += (hq + 2 * hkv) * hd
-    ffn = (3 if cfg.mlp_gated else 2) * d * cfg.d_ff
-    per_layer = attn + ffn + 2 * d
-    return cfg.num_layers * per_layer + d + cfg.vocab_size * d
+    """Weights one token multiplies: every layer's projections and FFN (for
+    routed experts, those it activates; biases and norm gains included)
+    and the output head; the embedding lookup is a gather, not a
+    product."""
+    return family.of(cfg).matmul_params(cfg)
 
 
 def attn_flops(cfg, ctx: int) -> int:
-    """QK^T and PV of one query over ``ctx`` keys, all layers."""
-    return 4 * ctx * cfg.num_heads * cfg.head_dim * cfg.num_layers
+    """The attention's products of one query over ``ctx`` keys, all
+    layers."""
+    return family.of(cfg).attn_flops(cfg, ctx)
 
 
 def token_flops(cfg, ctx: int) -> int:
@@ -37,16 +35,13 @@ def token_flops(cfg, ctx: int) -> int:
 
 def prompt_flops(cfg, n: int) -> int:
     """A prompt of ``n`` tokens none of which was sent before: token ``i``
-    attends to ``i + 1`` keys."""
-    tri = n * (n + 1) // 2
-    return n * 2 * matmul_params(cfg) + 4 * tri * cfg.num_heads \
-        * cfg.head_dim * cfg.num_layers
+    attends to ``i + 1`` keys (``attn_flops`` is linear in the keys)."""
+    return n * 2 * matmul_params(cfg) + attn_flops(cfg, n * (n + 1) // 2)
 
 
 def kv_bytes_per_token(cfg, kv_dtype: str = "bf16") -> int:
-    """K and V of one token in every layer."""
-    return cfg.num_layers * 2 * cfg.num_kv_heads * cfg.head_dim \
-        * KV_BYTES[kv_dtype]
+    """What the KV cache holds for one token in every layer."""
+    return family.of(cfg).kv_bytes_per_token(cfg, kv_dtype)
 
 
 def decode_attn_bytes(cfg, ctx: int, kv_dtype: str = "bf16") -> int:

@@ -7,12 +7,15 @@ A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
 configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); its offered rate and the limit of its
 correctness check are in ``bench/cells/<cell>.json``; each metric is read
-by ``bench/metrics/<metric>.py``.  The harness finds all of them by name,
-so a new cell, configuration, mix or metric is new files only.
+by ``bench/metrics/<metric>.py``; the configuration's ``model_type`` names
+its family, ``bench/families/<model_type>.py`` (``bench/family.py``).  The
+harness finds all of them by name, so a new cell, configuration, mix,
+metric or model family is new files only.
 
 In order, a run:
   1. refuses to run without a TPU, or with fewer chips than the cell asks;
-  2. draws the weights on the device from ``--seed`` (``bench/model.py``)
+  2. draws the weights on the device from ``--seed`` (``bench/model.py``,
+     by the configuration's family)
      and builds the engine with ``launch/serve.py``'s ``real_engine`` from
      every engine setting the configuration states, then checks the built
      engine against them;
@@ -291,12 +294,16 @@ def warm_up(fe, gen: loadgen.Traffic, conf: dict, log):
     dispatches (half the pool and all of it, as the agents keep three
     quarters of it busy): half a pool of flows of one length, and a full
     pool that drains one flow at a time (``drain_lengths``), for each
-    segment length.  Served like any traffic."""
+    segment length.  Served like any traffic.  A backend that stages
+    prefills on a second device takes the prompt lengths no more at a
+    time than it stages at once, so each length warms its staged path."""
     lengths = loadgen.prompt_lengths(gen.spec)
     slots = int(conf["pool_slots"])
+    group = min(slots, getattr(fe.engine.backend, "prefill_inflight_max",
+                               slots))
     t = time.perf_counter()
-    for i in range(0, len(lengths), slots):  # no more than the pool holds
-        serve_all(fe, [(gen.prompt(n), 2) for n in lengths[i:i + slots]])
+    for i in range(0, len(lengths), group):  # no more than the pool holds
+        serve_all(fe, [(gen.prompt(n), 2) for n in lengths[i:i + group]])
     log(f"[warm] {len(lengths)} prompt lengths {lengths[0]}..{lengths[-1]}"
         f" in {time.perf_counter() - t:.1f} s")
     for k, n in kv_buckets(gen.spec, int(conf["max_len"])):
@@ -502,7 +509,7 @@ def prepare(args, bench: dict, data_dir: str, require_tpu: bool, log):
     kind = devs[0].device_kind
     if require_tpu and kind not in peaks_all:
         raise NoChip(f"no peaks for device kind {kind!r} in peaks.json")
-    cfg = model.model_config(conf)
+    cfg = model.model_config(conf, data_dir)
     dtype = jax.numpy.bfloat16 if conf["dtype"] == "bfloat16" \
         else jax.numpy.float32
     key = jax.random.PRNGKey(int(loadgen.seed_rng(args.seed, "weights")
@@ -514,6 +521,8 @@ def prepare(args, bench: dict, data_dir: str, require_tpu: bool, log):
         f"{cfg.d_ff}, vocab {cfg.vocab_size}; weights in "
         f"{time.perf_counter() - T_START:.1f} s since start")
     eng = build_engine(conf, cfg, params, chips)
+    log(f"[setup] backend {type(eng.backend).__name__}, dual device "
+        f"{eng.stats().get('dual_device', False)}")
     keys = CompileLog(eng.backend)
     fe = ServingFrontend(eng, max_buffered_tokens=MAX_BUFFERED).start()
     rate = cell_file["reactive_rate_per_s"] if args.rate is None \
@@ -541,7 +550,8 @@ def run(args, *, bench: dict = None, data_dir: str = BENCH_DIR,
         require_tpu: bool = True, log=None) -> dict:
     """One run of one cell; returns the result object (``main`` prints
     it).  The CPU tests pass their own ``bench`` and ``data_dir`` (which
-    holds ``configs/``, ``traffic/`` and ``cells/``) and
+    holds ``configs/``, ``traffic/``, ``cells/`` and may hold
+    ``families/``) and
     ``require_tpu=False`` to drive a run at a tiny size."""
     import jax
     import numpy as np
@@ -612,7 +622,9 @@ def run(args, *, bench: dict = None, data_dir: str = BENCH_DIR,
                 log(f"[trace] {red['window_s']:.3f} s traced; the trace's "
                     f"clock and the host's differ by {drift * 1e3:.3f} ms "
                     f"between the marks")
-            busy = [d["busy_s"] for d in red["devices"]]
+            # averaged over the devices the cell's program used: a cell
+            # may hold chips on which nothing runs
+            busy = [d["busy_s"] for d in red["devices"] if d["busy_s"] > 0]
             device["busy_s"] = float(np.mean(busy)) if busy else 0.0
             device["window_s"] = red["window_s"]
             d0 = red["devices"][0] if red["devices"] else {"ops": [],

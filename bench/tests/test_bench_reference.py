@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bench import family
 from bench import reference as bref
 from bench.model import init_weights
 from repro.configs import get_tiny_config
@@ -13,7 +14,9 @@ from repro.models.reference import reference_logits as program_reference
 
 @pytest.fixture(scope="module")
 def tiny():
-    cfg = get_tiny_config("qwen2.5-32b")  # qkv bias, untied head
+    # qkv bias, untied head; the program's config, served by the dense
+    # family the benchmark binds to it
+    cfg = family.bind(get_tiny_config("qwen2.5-32b"), family.load("qwen2"))
     params = init_weights(cfg, jax.random.PRNGKey(0), jnp.float32)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 40), 0,
                               cfg.vocab_size)

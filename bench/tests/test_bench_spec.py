@@ -49,6 +49,21 @@ def test_names_and_keys_follow_the_contract():
         assert 0.01 <= m["bound"] <= 0.25
 
 
+def test_each_pair_of_config_and_traffic_once():
+    pairs = [(w["config"], w["traffic"]) for w in SPEC["workloads"]]
+    assert len(pairs) == len(set(pairs))
+
+
+def test_staged_mix_is_the_agent_mix():
+    """The dual-stage cell is paired with the one-chip 3B cell: its mix has a
+    name of its own but offers the same work."""
+    def mix(name):
+        d = json.load(open(os.path.join(BENCH, "traffic", f"{name}.json")))
+        d.pop("why")
+        return d
+    assert mix("agent_mix_staged") == mix("agent_mix")
+
+
 def test_config_file_declares_reductions():
     for c in SPEC["configs"]:
         conf = model.load_config(c["name"])

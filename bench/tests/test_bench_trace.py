@@ -67,3 +67,23 @@ def test_clock_marks_map_the_trace_onto_the_host():
     lo, hi = tr.to_host(red, [100.0, 103.0])
     assert abs(lo - 99.0) < 1e-9 and abs(hi - 104.0) < 1e-6
     assert tr.to_host(dict(red, marks_ns=[]), [100.0, 103.0]) is None
+
+
+def test_prefill_device_busy_share_reads_device_one():
+    """Device 1's busy union over the traced interval: 0.2-0.6 s and
+    2.0-2.1 s of 4 s; no value from a trace of one device."""
+    import json
+    import os
+
+    from bench import run
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "data", "traces", "two_plane.json")) as f:
+        trace = json.load(f)
+    read = run.metric_reader("prefill_device_busy_share",
+                             os.path.join(run.BENCH_DIR, "metrics"))
+    for n in (2, 4):
+        red = tr.reduce(trace, n)
+        assert red["window_s"] == 4.0
+        assert abs(read({"trace": red}) - 12.5) < 1e-9
+    assert read({"trace": tr.reduce(trace, 1)}) is None
+    assert read({"trace": None}) is None
